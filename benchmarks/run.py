@@ -205,4 +205,7 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    from repro.device import use_compile_cache
+
+    use_compile_cache()
     main()

@@ -98,7 +98,7 @@ def _apsp_squaring(d: np.ndarray, n: int, use_kernel: bool, block: int,
 
 
 def apsp_from_lengths(lengths: np.ndarray, use_kernel: bool = True,
-                      block: int = 256,
+                      block: Optional[int] = None,
                       max_squarings: Optional[int] = None) -> np.ndarray:
     """APSP over an arbitrary nonnegative (n, n) edge-length matrix.
 
@@ -110,17 +110,19 @@ def apsp_from_lengths(lengths: np.ndarray, use_kernel: bool = True,
     oracle keeps the host loop over the jnp product. This is the
     weighted-shortest-path oracle the throughput engine calls once per
     multiplicative-weights round, batched over all router pairs at once.
+    ``block`` defaults to the autotuner's min-plus entry for the padded
+    size (128 blocks fit v5e's scoped VMEM; 256 with ``sub_k`` 8 does not).
     """
     lengths = np.asarray(lengths, np.float32)
     n = lengths.shape[0]
     if not use_kernel:
         if max_squarings is None:
             max_squarings = max(1, int(np.ceil(np.log2(max(2, n)))))
-        return _apsp_squaring(lengths, n, use_kernel=False, block=block,
-                              max_squarings=max_squarings)
+        return _apsp_squaring(lengths, n, use_kernel=False,
+                              block=block or 128, max_squarings=max_squarings)
     from .wavefront import squaring_apsp_device
 
-    pad = (-n) % max(block, 128)
+    pad = (-n) % max(block or 128, 128)
     d = lengths
     if pad:
         d = np.pad(d, ((0, pad), (0, pad)), constant_values=_INF)

@@ -62,6 +62,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from ...device import resolve_interpret
 from .wavefront import pad_block, pad_operand
 
 __all__ = [
@@ -73,7 +74,7 @@ __all__ = [
     "bfs_dist_sigma", "widest_divisor_block",
 ]
 
-_INF = jnp.float32(jnp.inf)
+_INF = np.float32(np.inf)
 
 #: the one mesh axis every sharded engine uses (1-D row mesh)
 ROW_AXIS = "rows"
@@ -81,15 +82,14 @@ ROW_AXIS = "rows"
 #: f32 row/col tile width every padded size is a multiple of
 _TILE = 128
 
+#: widest block edge of the streaming engines' kernels: a (512, 512) f32
+#: output block with its double-buffered operands fits v5e's 16 MiB scoped
+#: VMEM; the TPU compiler refuses a 2048-wide one
+_MAX_BLOCK = 512
+
 #: dense adjacency larger than this (bytes) makes the tiled engine stream
 #: CSR-built panels instead of keeping the full matrix device-resident
 _ADJ_BUDGET = 1 << 28
-
-
-def _interpret_default() -> bool:
-    from ... import kernels
-
-    return kernels.ops.INTERPRET
 
 
 def _pad128(n: int) -> int:
@@ -182,7 +182,6 @@ def _fit_sharded(p: int, num_shards: int, block: Optional[int],
 @functools.lru_cache(maxsize=None)
 def _dist_mult_sharded_fn(mesh, batched: bool, bm: int, block: int,
                           interpret: bool, telemetry: bool = False):
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     from ... import kernels
@@ -259,9 +258,9 @@ def _dist_mult_sharded_fn(mesh, batched: bool, bm: int, block: int,
     out_spec = P(*lead, ROW_AXIS, None)
     out_specs = ((out_spec, out_spec, (P(), P()))
                  if telemetry else (out_spec, out_spec))
-    fn = shard_map(local, mesh=mesh,
-                   in_specs=(P(*lead, None, None),),
-                   out_specs=out_specs, check_rep=False)
+    fn = jax.shard_map(local, mesh=mesh,
+                       in_specs=(P(*lead, None, None),),
+                       out_specs=out_specs, check_vma=False)
     return jax.jit(fn)
 
 
@@ -282,8 +281,7 @@ def dist_mult_sharded(adj: jnp.ndarray, mesh, bm: Optional[int] = None,
     ``(levels, sizes)`` — globally psum'd per-level newly-reached pair
     counts, same convention as `wavefront.dist_mult_device`.
     """
-    if interpret is None:
-        interpret = _interpret_default()
+    interpret = resolve_interpret(interpret)
     p = adj.shape[-1]
     num_shards = mesh.shape[ROW_AXIS]
     batched = adj.ndim == 3
@@ -343,7 +341,6 @@ def sharded_dist_mult(adj: np.ndarray, mesh=None,
 @functools.lru_cache(maxsize=None)
 def _ecmp_sharded_fn(mesh, batched: bool, bm: int, block: int,
                      interpret: bool):
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     from ...kernels.semiring import (COUNTING, semiring_matmul_batched_pallas,
@@ -391,9 +388,9 @@ def _ecmp_sharded_fn(mesh, batched: bool, bm: int, block: int,
     lead = (None,) * (1 if batched else 0)
     row_spec = P(*lead, ROW_AXIS, None)
     rep_spec = P(*lead, None, None)
-    fn = shard_map(local, mesh=mesh,
-                   in_specs=(row_spec, row_spec, rep_spec),
-                   out_specs=rep_spec, check_rep=False)
+    fn = jax.shard_map(local, mesh=mesh,
+                       in_specs=(row_spec, row_spec, rep_spec),
+                       out_specs=rep_spec, check_vma=False)
     return jax.jit(fn)
 
 
@@ -410,8 +407,7 @@ def ecmp_loads_sharded(dist: jnp.ndarray, mult: jnp.ndarray,
     matrix; matches `wavefront.ecmp_loads_device` to f32 round-off (the
     per-source partials are summed in a different order).
     """
-    if interpret is None:
-        interpret = _interpret_default()
+    interpret = resolve_interpret(interpret)
     p = adj.shape[-1]
     num_shards = mesh.shape[ROW_AXIS]
     batched = adj.ndim == 3
@@ -464,31 +460,11 @@ def _router_count(source) -> int:
     return source.n if hasattr(source, "csr") else np.asarray(source).shape[-1]
 
 
-def _largest_divisor_block(size: int, cap: int) -> int:
-    """Largest power-of-two multiple of 128 <= cap that divides ``size``.
-
-    Interpret mode re-materializes the whole output block-by-block, so the
-    grid wants as FEW programs as possible — wide blocks are ~30x faster
-    than the 128-default at 16k columns (measured 27.4s -> 0.5s per panel
-    product). On real TPUs the autotuner's table takes precedence anyway.
-    """
-    b = _TILE
-    while b * 2 <= cap and size % (b * 2) == 0:
-        b *= 2
-    return b
-
-
 def widest_divisor_block(size: int, cap: int) -> int:
     """Largest multiple of ``_TILE`` <= cap dividing ``size`` — ANY
-    multiplier, not just powers of two.
-
-    100k-class padded extents rarely carry big power-of-two factors
-    (104960 = 2^9 x 5 x 41: `_largest_divisor_block` stops at 512, a
-    205-block column grid), but they do carry big 128-multiples (10496 =
-    128 x 82). The extreme sweep picks its ``block=`` with this so each
-    level stays a handful of wide gemm blocks instead of tens of
-    thousands of interpret-mode dispatches. ``size`` must be a multiple
-    of ``_TILE`` (padded extents always are).
+    multiplier, not just powers of two (3456 = 128 x 27 takes 384, where
+    a power-of-two search stops at 128). ``size`` must be a multiple of
+    ``_TILE`` (padded extents always are).
     """
     q = size // _TILE
     qcap = max(1, cap // _TILE)
@@ -502,6 +478,13 @@ def widest_divisor_block(size: int, cap: int) -> int:
                 best = max(best, q // d)
         d += 1
     return best * _TILE
+
+
+def _stream_block(size: int, block: Optional[int]) -> int:
+    """Kernel block edge for one padded extent of the streaming engines:
+    the widest 128-multiple dividing ``size`` up to ``block`` (when given)
+    and never past `_MAX_BLOCK`."""
+    return widest_divisor_block(size, min(block or _MAX_BLOCK, _MAX_BLOCK))
 
 
 def _record_panel(nbytes: int, dtype, h2d: bool = True) -> None:
@@ -635,10 +618,9 @@ def _tile_shape(t: int, cap: int = 512) -> Tuple[int, int]:
         tp = t + ((-t) % 8)       # f32 sublane tile; one row block
         return tp, tp
     # big tiles pad to the 128 lane tile (<= 127 phantom rows) so the row
-    # block never degrades below 128 — interpret mode pays per grid program
-    # (see _largest_divisor_block)
+    # block never degrades below 128
     tp = _pad128(t)
-    return tp, _largest_divisor_block(tp, cap)
+    return tp, widest_divisor_block(tp, cap)
 
 
 def tiled_dist_mult_tiles(
@@ -682,17 +664,10 @@ def tiled_dist_mult_tiles(
             adjacency_budget=adjacency_budget, block=block,
             interpret=interpret, packed=packed)
         return
-    if interpret is None:
-        interpret = _interpret_default()
+    interpret = resolve_interpret(interpret)
     fill, n = _adjacency_source(source)
     pc = _pad128(n)                                # padded column count
-    if block is not None and pc % block == 0:
-        bn = bk = block
-    else:
-        # wide output blocks: interpret mode pays per grid program (see
-        # _largest_divisor_block), and the N dimension is the long one
-        bn = _largest_divisor_block(pc, 2048)
-        bk = _largest_divisor_block(pc, 512)
+    bn = bk = _stream_block(pc, block)
     ids_all, base = _resolve_source_ids(n, sources, source_ids)
     tile_rows = max(1, min(tile_rows, len(ids_all)))
 
@@ -711,7 +686,7 @@ def tiled_dist_mult_tiles(
         panel_rows -= _TILE
     # the panel product's K dimension is panel_rows, not pc — its K block
     # must divide THAT (panel_rows | pc, so this also divides pc)
-    bk_panel = _largest_divisor_block(panel_rows, bk)
+    bk_panel = widest_divisor_block(panel_rows, bk)
     panel_buf = np.zeros((panel_rows, pc), adtype)   # the pinned pump
     adj_dev = None
     if not stream:
@@ -970,7 +945,6 @@ def _dist_mult_composed_fn(mesh, bm: int, bn: int, bk_panel: int,
     `fori_loop` ring (`lax.ppermute`) walks every K-slab past every shard,
     so the full product is accumulated without a dense or replicated
     adjacency ever existing."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     from ...kernels.semiring import (COUNTING, DIST_UNREACHED, MULT_SAT,
@@ -1050,8 +1024,8 @@ def _dist_mult_composed_fn(mesh, bm: int, bn: int, bk_panel: int,
         return dist, mult, sat
 
     row = P(ROW_AXIS, None)
-    fn = shard_map(local, mesh=mesh, in_specs=(row, row, row),
-                   out_specs=(row, row, P()), check_rep=False)
+    fn = jax.shard_map(local, mesh=mesh, in_specs=(row, row, row),
+                       out_specs=(row, row, P()), check_vma=False)
     return jax.jit(fn)
 
 
@@ -1128,8 +1102,7 @@ def composed_dist_mult_tiles(
             adjacency_budget=adjacency_budget, block=block,
             interpret=interpret, packed=packed)
         return
-    if interpret is None:
-        interpret = _interpret_default()
+    interpret = resolve_interpret(interpret)
     fill, n = _adjacency_source(source)
     p = _pad128(n)
     p += (-p) % (num_shards * _TILE)
@@ -1144,12 +1117,8 @@ def composed_dist_mult_tiles(
             f"{adjacency_budget} — use more shards, packed=True (uint8 "
             f"panels), a larger budget, or the single-device streaming "
             f"engine (mesh=None)")
-    if block is not None and p % block == 0 and kp % block == 0:
-        bn = block
-        bk_panel = block
-    else:
-        bn = _largest_divisor_block(p, 2048)
-        bk_panel = _largest_divisor_block(kp, 512)
+    bn = _stream_block(p, block)
+    bk_panel = _stream_block(kp, block)
     if panel_rows is None:
         panel_rows = min(kp, max(_TILE, adjacency_budget // (8 * p * abytes)))
     panel_rows = max(_TILE,
@@ -1183,7 +1152,7 @@ def composed_dist_mult_tiles(
             bm = tp // num_shards
         else:
             tp = t + ((-t) % (num_shards * _TILE))
-            bm = _largest_divisor_block(tp // num_shards, 512)
+            bm = widest_divisor_block(tp // num_shards, _MAX_BLOCK)
         with obs.span("composed.tile", cat="composed", r0=r0, r1=r1,
                       packed=packed) as sp:
             eye, seed = _seed_tile(ids, tp, p, packed)
@@ -1275,9 +1244,9 @@ def main(argv=None) -> int:
                     help="row-shard over this many devices (composed "
                          "engine); default: single-device streaming")
     ap.add_argument("--block", type=int, default=None,
-                    help="explicit kernel block edge (must divide the "
-                         "padded column/K extents); larger blocks cut the "
-                         "per-block dispatch overhead at big sizes")
+                    help="widest kernel block edge to use (a 128-multiple; "
+                         "the engines never go past 512, the widest that "
+                         "fits v5e's scoped VMEM)")
     ap.add_argument("--check", type=int, default=2,
                     help="spot-check this many sources vs the CSR oracle")
     ap.add_argument("--checkpoint", default=None, metavar="FILE.json",
@@ -1352,4 +1321,7 @@ def main(argv=None) -> int:
 
 
 if __name__ == "__main__":
+    from ...device import use_compile_cache
+
+    use_compile_cache()
     raise SystemExit(main())

@@ -23,11 +23,9 @@ The matrix (kernel path, ``method`` wavefront or default)::
                                                engine is f32-only; packed
                                                rides the streaming family)
 
-``block=`` (explicit kernel block edge) is a grid knob INSIDE the
+``block=`` (widest kernel block edge) is a grid knob INSIDE the
 tiled/composed family, not a selection knob: it rides through to whichever
-streaming engine the matrix picks and never changes the choice (the
-extreme sweep sizes it per family via
-``distributed.widest_divisor_block``).
+streaming engine the matrix picks and never changes the choice.
 
 Rejected, with the reason in the error:
 

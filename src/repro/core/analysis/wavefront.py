@@ -48,18 +48,13 @@ import jax
 import jax.numpy as jnp
 
 from ... import obs
+from ...device import resolve_interpret
 
 __all__ = ["wavefront_dist_mult", "dist_mult_device", "ecmp_loads_device",
            "squaring_apsp_device", "pad_block", "pad_operand",
            "telemetry_attrs"]
 
-_INF = jnp.float32(jnp.inf)
-
-
-def _interpret_default() -> bool:
-    from ... import kernels
-
-    return kernels.ops.INTERPRET
+_INF = np.float32(np.inf)
 
 
 def pad_block(n: int, block: Optional[int] = None,
@@ -269,8 +264,7 @@ def dist_mult_device(adj: jnp.ndarray, block: Optional[int] = None,
     ``(dist, mult, sat, aux)`` with telemetry. Bit-equal (as integers) to
     the f32 engine while diameters and counts fit.
     """
-    if interpret is None:
-        interpret = _interpret_default()
+    interpret = resolve_interpret(interpret)
     p = adj.shape[-1]
     block = _fit_block(p, block, batched=adj.ndim == 3)
     return _dist_mult_fn(adj.ndim == 3, block, interpret, telemetry,
@@ -425,8 +419,7 @@ def ecmp_loads_device(dist: jnp.ndarray, mult: jnp.ndarray, adj: jnp.ndarray,
     must share a (.., p, p) block-multiple shape (phantom padding: dist
     +inf rows, mult/adj/demand 0). Returns the device (.., p, p) loads.
     """
-    if interpret is None:
-        interpret = _interpret_default()
+    interpret = resolve_interpret(interpret)
     p = dist.shape[-1]
     block = _fit_block(p, block, batched=dist.ndim == 3)
     if demand is None:
@@ -484,8 +477,7 @@ def squaring_apsp_device(d: jnp.ndarray, max_squarings: Optional[int] = None,
     """
     from ...kernels import autotune
 
-    if interpret is None:
-        interpret = _interpret_default()
+    interpret = resolve_interpret(interpret)
     p = d.shape[-1]
     if max_squarings is None:
         max_squarings = max(1, int(np.ceil(np.log2(p))))

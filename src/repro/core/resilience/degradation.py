@@ -559,4 +559,7 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
 
 
 if __name__ == "__main__":
+    from ...device import use_compile_cache
+
+    use_compile_cache()
     raise SystemExit(main())

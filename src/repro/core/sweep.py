@@ -450,7 +450,6 @@ def sweep_extreme(families: Optional[Sequence[str]] = None,
                   seed: int = 0, packed: bool = True, mesh=None,
                   tile_rows: Optional[int] = None,
                   adjacency_budget: Optional[int] = None,
-                  block_cap: Optional[int] = 16384,
                   throughput: bool = False) -> Dict:
     """Extreme-scale sweep: every family sized to ``target_routers``
     ROUTERS (not servers — at 100k the router count is the analysis cost),
@@ -464,13 +463,7 @@ def sweep_extreme(families: Optional[Sequence[str]] = None,
     target records an ``error`` row instead of aborting the sweep — the
     table shows the gap. Returns ``{"rows": [...], ...}`` for
     :func:`format_extreme_table` / the BENCH_8 baseline row.
-
-    ``block_cap`` sizes each family's kernel block to the largest divisor
-    of its padded extent below the cap — at 100k the default caps would
-    dispatch ~13k interpret-mode blocks per level; big blocks keep each
-    level gemm-bound. ``block_cap=None`` keeps the engine defaults.
     """
-    from .analysis.distributed import _pad128, widest_divisor_block
     from .analysis.estimator import sampled_sources_summary
 
     families = list(families) if families else topo.families()
@@ -489,14 +482,12 @@ def sweep_extreme(families: Optional[Sequence[str]] = None,
                 obs.log("sweep.extreme.skip", family=fam, error=str(exc))
                 rows.append({"family": fam, "error": str(exc)})
                 continue
-            block = (widest_divisor_block(_pad128(g.n), block_cap)
-                     if block_cap else None)
             with obs.span("sweep.extreme.family", cat="sweep", family=fam,
-                          routers=g.n, block=block):
+                          routers=g.n):
                 s = sampled_sources_summary(
                     g, k=k_sources, seed=seed, mesh=mesh,
                     tile_rows=tile_rows, packed=packed,
-                    adjacency_budget=adjacency_budget, block=block,
+                    adjacency_budget=adjacency_budget,
                     throughput=throughput)
             spec = g.meta.get("spec")
             est = s["estimates"]
@@ -634,10 +625,6 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
     ap.add_argument("--tile-rows", type=int, default=None)
     ap.add_argument("--adjacency-budget", type=int, default=None,
                     help="device bytes before adjacency panels stream")
-    ap.add_argument("--block-cap", type=int, default=16384,
-                    help="extreme mode: per-family kernel block = largest "
-                         "128-multiple divisor of the padded extent under "
-                         "this cap (0: engine defaults)")
     ap.add_argument("--throughput", action="store_true",
                     help="extreme mode: add the sampled ECMP saturation-"
                          "throughput estimate (host Brandes, O(E)/source)")
@@ -659,7 +646,6 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
             packed=not args.no_packed, mesh=mesh,
             tile_rows=args.tile_rows,
             adjacency_budget=args.adjacency_budget,
-            block_cap=args.block_cap or None,
             throughput=args.throughput)
         table = format_extreme_table(result)
         print(table)
@@ -707,4 +693,7 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
 
 
 if __name__ == "__main__":
+    from ..device import use_compile_cache
+
+    use_compile_cache()
     raise SystemExit(main())

@@ -2,19 +2,21 @@
 
 The semiring kernels expose (bm, bn, bk) block shapes (and ``sub_k`` slab
 width on the VPU path) as free parameters; the best choice depends on the
-problem size and the backend (interpret-mode CPU here, Mosaic on real TPU).
+problem size and the backend (the Pallas interpreter on the CPU, Mosaic on
+a TPU, whose scoped VMEM bounds the block shapes that compile).
 This module owns one small mechanism:
 
 * a **persisted tuning table** — JSON mapping ``op -> shape bucket ->
-  config``. A checked-in default (`tuning_table.json`, tuned on this
-  container) ships with the package; a user table (``$REPRO_TUNE_TABLE``,
-  default ``~/.cache/repro/tuning_table.json``) overlays it, so re-tuned
-  entries persist across processes without touching the repo.
+  config``. The committed `tuning_table.json` is the table; only when
+  ``$REPRO_TUNE_TABLE`` is set does that file overlay it. Block shapes
+  change what is compiled, so nothing outside the checkout feeds them
+  unless asked for.
 * :func:`resolve` — the ops-layer hook: explicit keyword arguments always
   win, anything left unspecified comes from the table (falling back to the
   op's registered default config).
 * :func:`autotune` — time a list of candidate configs on a given shape and
-  persist the winner into the user table. ``python -m repro.kernels.autotune
+  persist the winner (into the overlay when ``$REPRO_TUNE_TABLE`` is set,
+  else into the committed table). ``python -m repro.kernels.autotune
   --op frontier_step --size 1024`` from the CLI.
 
 Shapes are bucketed to the next power of two (min 128) per dimension, so one
@@ -32,14 +34,16 @@ from typing import Dict, Iterable, List, Optional
 __all__ = ["resolve", "autotune", "shape_key", "load_table", "save_entry",
            "DEFAULTS", "CANDIDATES"]
 
-#: per-op fallback configs (also the legacy defaults of the ops layer)
+#: per-op fallback configs. The VPU (min-plus) ops stay at 128 blocks:
+#: their (bm, sub_k, bn) broadcast slabs overflow v5e's 16 MiB scoped VMEM
+#: at 256 blocks with sub_k 8 or 32 (tests/test_tpu_compile.py)
 DEFAULTS: Dict[str, Dict[str, int]] = {
     "minplus": {"bm": 128, "bn": 128, "bk": 128, "sub_k": 8},
     "minplus_count": {"bm": 128, "bn": 128, "bk": 128, "sub_k": 8},
     "count": {"bm": 128, "bn": 128, "bk": 128},
     "boolean": {"bm": 128, "bn": 128, "bk": 128},
     "frontier_step": {"bm": 128, "bn": 128, "bk": 128},
-    "batched_minplus": {"bm": 256, "bn": 256, "bk": 256, "sub_k": 8},
+    "batched_minplus": {"bm": 128, "bn": 128, "bk": 128, "sub_k": 8},
     "batched_count": {"bm": 256, "bn": 256, "bk": 256},
     "batched_frontier_step": {"bm": 256, "bn": 256, "bk": 256},
 }
@@ -67,11 +71,9 @@ CANDIDATES: Dict[str, List[Dict[str, int]]] = {
 _SHIPPED = pathlib.Path(__file__).with_name("tuning_table.json")
 
 
-def _user_table_path() -> pathlib.Path:
+def _overlay_path() -> Optional[pathlib.Path]:
     env = os.environ.get("REPRO_TUNE_TABLE")
-    if env:
-        return pathlib.Path(env)
-    return pathlib.Path.home() / ".cache" / "repro" / "tuning_table.json"
+    return pathlib.Path(env) if env else None
 
 
 def _read_json(path: pathlib.Path) -> Dict:
@@ -107,7 +109,8 @@ _TABLE_CACHE: Optional[Dict] = None
 
 
 def load_table(refresh: bool = False) -> Dict[str, Dict[str, Dict[str, int]]]:
-    """Shipped table overlaid with the user table (user entries win).
+    """The committed table, overlaid by ``$REPRO_TUNE_TABLE`` when set
+    (overlay entries win).
 
     Cached in-process (the ops layer consults it on every call); refreshed
     after :func:`save_entry` or on ``refresh=True``.
@@ -115,16 +118,19 @@ def load_table(refresh: bool = False) -> Dict[str, Dict[str, Dict[str, int]]]:
     global _TABLE_CACHE
     if _TABLE_CACHE is None or refresh:
         table = _read_json(_SHIPPED)
-        for op, entries in _read_json(_user_table_path()).items():
-            table.setdefault(op, {}).update(entries)
+        overlay = _overlay_path()
+        if overlay is not None:
+            for op, entries in _read_json(overlay).items():
+                table.setdefault(op, {}).update(entries)
         _TABLE_CACHE = table
     return _TABLE_CACHE
 
 
 def save_entry(op: str, key: str, config: Dict[str, int]) -> pathlib.Path:
-    """Persist one tuned entry into the user table."""
+    """Persist one tuned entry: into ``$REPRO_TUNE_TABLE`` when set, else
+    into the committed table."""
     global _TABLE_CACHE
-    path = _user_table_path()
+    path = _overlay_path() or _SHIPPED
     path.parent.mkdir(parents=True, exist_ok=True)
     table = _read_json(path)
     table.setdefault(op, {})[key] = dict(config)
@@ -287,7 +293,7 @@ def main(argv=None) -> int:
                    persist=not args.no_persist)
     print(f"[autotune] {args.op} @ {res.pop('key')}: best {res}")
     if not args.no_persist:
-        print(f"[autotune] persisted to {_user_table_path()}")
+        print(f"[autotune] persisted to {_overlay_path() or _SHIPPED}")
     return 0
 
 

@@ -8,6 +8,8 @@ just the TROPICAL instantiation.
 """
 from __future__ import annotations
 
+from typing import Optional
+
 import jax.numpy as jnp
 
 from .semiring import TROPICAL, semiring_matmul_pallas
@@ -17,12 +19,10 @@ __all__ = ["minplus_matmul_pallas"]
 
 def minplus_matmul_pallas(a: jnp.ndarray, b: jnp.ndarray, *,
                           bm: int = 128, bn: int = 128, bk: int = 128,
-                          sub_k: int = 8, interpret: bool = True) -> jnp.ndarray:
-    """Tropical product of (M, K) and (K, N); M, N, K must divide into blocks.
-
-    ``interpret=True`` executes the kernel body on CPU (this container);
-    on TPU pass interpret=False.
-    """
+                          sub_k: int = 8,
+                          interpret: Optional[bool] = None) -> jnp.ndarray:
+    """Tropical product of (M, K) and (K, N); M, N, K must divide into
+    blocks. ``interpret`` defaults to the backend's choice."""
     (out,) = semiring_matmul_pallas(TROPICAL, (a,), (b,), bm=bm, bn=bn, bk=bk,
                                     sub_k=sub_k, interpret=interpret)
     return out

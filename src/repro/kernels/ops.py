@@ -1,8 +1,9 @@
 """Jit'd public wrappers for the Pallas kernels.
 
-Every op auto-pads to block multiples, dispatches to the Pallas kernel (in
-interpret mode on CPU — this container's runtime — and compiled on real TPU),
-and exposes a ``use_kernel=False`` escape hatch to the jnp oracle in `ref`.
+Every op auto-pads to block multiples, dispatches to the Pallas kernel
+(interpreted on the CPU backend, compiled on a TPU — decided once, by
+`repro.device.resolve_interpret`), and exposes a ``use_kernel=False``
+escape hatch to the jnp oracle in `ref`.
 
 Block shapes (and ``sub_k`` on the VPU path) default to the autotuner's
 persisted tuning table (`autotune.resolve`); passing them explicitly always
@@ -31,10 +32,6 @@ __all__ = ["minplus_matmul", "reachability_step", "value_histogram",
            "batched_count_matmul", "batched_frontier_step",
            "batched_frontier_step_packed"]
 
-# CPU containers run the kernels through the Pallas interpreter; on TPU flip
-# this (or pass interpret=False explicitly) to run compiled Mosaic kernels.
-INTERPRET = jax.default_backend() != "tpu"
-
 
 def _pad_to(x: jnp.ndarray, bm: int, bn: int, fill) -> jnp.ndarray:
     m, n = x.shape
@@ -50,8 +47,7 @@ def _minplus_jit(a: jnp.ndarray, b: jnp.ndarray, bm: int, bn: int, bk: int,
     m, n = a.shape[0], b.shape[1]
     ap = _pad_to(a.astype(jnp.float32), bm, bk, TROPICAL.pad_a[0])
     bp = _pad_to(b.astype(jnp.float32), bk, bn, TROPICAL.pad_b[0])
-    out = minplus_matmul_pallas(ap, bp, bm=bm, bn=bn, bk=bk, sub_k=sub_k,
-                                interpret=INTERPRET)
+    out = minplus_matmul_pallas(ap, bp, bm=bm, bn=bn, bk=bk, sub_k=sub_k)
     return out[:m, :n]
 
 
@@ -70,8 +66,7 @@ def _reachability_jit(a: jnp.ndarray, b: jnp.ndarray, bm: int, bn: int,
     m, n = a.shape[0], b.shape[1]
     ap = _pad_to(a.astype(jnp.float32), bm, bk, BOOLEAN.pad_a[0])
     bp = _pad_to(b.astype(jnp.float32), bk, bn, BOOLEAN.pad_b[0])
-    out = reachability_step_pallas(ap, bp, bm=bm, bn=bn, bk=bk,
-                                   interpret=INTERPRET)
+    out = reachability_step_pallas(ap, bp, bm=bm, bn=bn, bk=bk)
     return out[:m, :n]
 
 
@@ -89,8 +84,7 @@ def _count_jit(a: jnp.ndarray, b: jnp.ndarray, bm: int, bn: int,
     m, n = a.shape[0], b.shape[1]
     ap = _pad_to(a.astype(jnp.float32), bm, bk, COUNTING.pad_a[0])
     bp = _pad_to(b.astype(jnp.float32), bk, bn, COUNTING.pad_b[0])
-    (out,) = semiring_matmul_pallas(COUNTING, (ap,), (bp,), bm=bm, bn=bn,
-                                    bk=bk, interpret=INTERPRET)
+    (out,) = semiring_matmul_pallas(COUNTING, (ap,), (bp,), bm=bm, bn=bn, bk=bk)
     return out[:m, :n]
 
 
@@ -116,8 +110,7 @@ def _minplus_count_jit(da: jnp.ndarray, ca: jnp.ndarray, db: jnp.ndarray,
     dbp = _pad_to(db.astype(jnp.float32), bk, bn, TROPICAL_COUNT.pad_b[0])
     cbp = _pad_to(cb.astype(jnp.float32), bk, bn, TROPICAL_COUNT.pad_b[1])
     d, c = semiring_matmul_pallas(TROPICAL_COUNT, (dap, cap), (dbp, cbp),
-                                  bm=bm, bn=bn, bk=bk, sub_k=sub_k,
-                                  interpret=INTERPRET)
+                                  bm=bm, bn=bn, bk=bk, sub_k=sub_k)
     return d[:m, :n], c[:m, :n]
 
 
@@ -144,8 +137,7 @@ def _frontier_step_jit(f: jnp.ndarray, a: jnp.ndarray, d: jnp.ndarray,
     # dist pads with +inf (unreached); padded counts are 0, so the
     # first-reach mask never fires in the padding
     dp = _pad_to(d.astype(jnp.float32), bm, bn, jnp.inf)
-    out = frontier_step_pallas(fp, ap, dp, bm=bm, bn=bn, bk=bk,
-                               interpret=INTERPRET)
+    out = frontier_step_pallas(fp, ap, dp, bm=bm, bn=bn, bk=bk)
     return out[:m, :n]
 
 
@@ -172,8 +164,7 @@ def _frontier_step_packed_jit(f: jnp.ndarray, a: jnp.ndarray, d: jnp.ndarray,
     # dist pads with the unreached sentinel; padded counts stay 0, so the
     # first-reach mask never fires in the padding
     dp = _pad_to(d.astype(jnp.int16), bm, bn, DIST_UNREACHED)
-    out = frontier_step_packed_pallas(fp, ap, dp, bm=bm, bn=bn, bk=bk,
-                                      interpret=INTERPRET)
+    out = frontier_step_packed_pallas(fp, ap, dp, bm=bm, bn=bn, bk=bk)
     return out[:m, :n]
 
 
@@ -206,8 +197,7 @@ def _batched_minplus_jit(a: jnp.ndarray, b: jnp.ndarray, bm: int, bn: int,
     ap = _pad_to_batched(a.astype(jnp.float32), bm, bk, TROPICAL.pad_a[0])
     bp = _pad_to_batched(b.astype(jnp.float32), bk, bn, TROPICAL.pad_b[0])
     (out,) = semiring_matmul_batched_pallas(TROPICAL, (ap,), (bp,), bm=bm,
-                                            bn=bn, bk=bk, sub_k=sub_k,
-                                            interpret=INTERPRET)
+                                            bn=bn, bk=bk, sub_k=sub_k)
     return out[:, :m, :n]
 
 
@@ -217,8 +207,6 @@ def batched_minplus_matmul(a: jnp.ndarray, b: jnp.ndarray, bm: int = None,
     """Tropical product over a stacked leading axis: (B, M, K) x (B, K, N).
 
     One kernel launch for the whole stack — the sweep driver's APSP path.
-    Blocks default to 256 (vs. 128 for the 2D op): the stacked workload
-    amortizes per-block dispatch, and bigger tiles cut block count 8x.
     """
     cfg = autotune.resolve("batched_minplus", a.shape[1], b.shape[2],
                            a.shape[2], bm=bm, bn=bn, bk=bk, sub_k=sub_k)
@@ -232,7 +220,7 @@ def _batched_count_jit(a: jnp.ndarray, b: jnp.ndarray, bm: int, bn: int,
     ap = _pad_to_batched(a.astype(jnp.float32), bm, bk, COUNTING.pad_a[0])
     bp = _pad_to_batched(b.astype(jnp.float32), bk, bn, COUNTING.pad_b[0])
     (out,) = semiring_matmul_batched_pallas(COUNTING, (ap,), (bp,), bm=bm,
-                                            bn=bn, bk=bk, interpret=INTERPRET)
+                                            bn=bn, bk=bk)
     return out[:, :m, :n]
 
 
@@ -252,8 +240,7 @@ def _batched_frontier_step_jit(f: jnp.ndarray, a: jnp.ndarray,
     fp = _pad_to_batched(f.astype(jnp.float32), bm, bk, 0.0)
     ap = _pad_to_batched(a.astype(jnp.float32), bk, bn, 0.0)
     dp = _pad_to_batched(d.astype(jnp.float32), bm, bn, jnp.inf)
-    out = frontier_step_batched_pallas(fp, ap, dp, bm=bm, bn=bn, bk=bk,
-                                       interpret=INTERPRET)
+    out = frontier_step_batched_pallas(fp, ap, dp, bm=bm, bn=bn, bk=bk)
     return out[:, :m, :n]
 
 
@@ -275,7 +262,7 @@ def _batched_frontier_step_packed_jit(f: jnp.ndarray, a: jnp.ndarray,
     ap = _pad_to_batched(a, bk, bn, 0)
     dp = _pad_to_batched(d.astype(jnp.int16), bm, bn, DIST_UNREACHED)
     out = frontier_step_packed_batched_pallas(fp, ap, dp, bm=bm, bn=bn,
-                                              bk=bk, interpret=INTERPRET)
+                                              bk=bk)
     return out[:, :m, :n]
 
 
@@ -294,8 +281,7 @@ def value_histogram(x: jnp.ndarray, num_bins: int,
                     bm: int = 256, bn: int = 256) -> jnp.ndarray:
     """Histogram of floor(x) over [0, num_bins); pads with -1 (dropped)."""
     xp = _pad_to(x.astype(jnp.float32), bm, bn, -1.0)
-    return value_histogram_pallas(xp, num_bins, bm=bm, bn=bn,
-                                  interpret=INTERPRET)
+    return value_histogram_pallas(xp, num_bins, bm=bm, bn=bn)
 
 
 # oracle aliases so callers can ask for the reference implementation

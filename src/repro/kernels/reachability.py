@@ -7,6 +7,8 @@ never leaves VMEM; this module is just the BOOLEAN instantiation.
 """
 from __future__ import annotations
 
+from typing import Optional
+
 import jax.numpy as jnp
 
 from .semiring import BOOLEAN, semiring_matmul_pallas
@@ -16,7 +18,8 @@ __all__ = ["reachability_step_pallas"]
 
 def reachability_step_pallas(a: jnp.ndarray, b: jnp.ndarray, *,
                              bm: int = 128, bn: int = 128, bk: int = 128,
-                             interpret: bool = True) -> jnp.ndarray:
+                             interpret: Optional[bool] = None
+                             ) -> jnp.ndarray:
     """One reachability squaring step over {0,1} float masks."""
     (out,) = semiring_matmul_pallas(BOOLEAN, (a,), (b,), bm=bm, bn=bn, bk=bk,
                                     interpret=interpret)

@@ -11,10 +11,13 @@ data-dependent addressing anywhere.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from ..device import resolve_interpret
 
 __all__ = ["value_histogram_pallas"]
 
@@ -38,7 +41,7 @@ def _hist_kernel(x_ref, o_ref, *, num_bins: int, grid_n: int):
 
 def value_histogram_pallas(x: jnp.ndarray, num_bins: int, *,
                            bm: int = 256, bn: int = 256,
-                           interpret: bool = True) -> jnp.ndarray:
+                           interpret: Optional[bool] = None) -> jnp.ndarray:
     """Histogram floor(x) into [0, num_bins) over a 2D float array."""
     m, n = x.shape
     assert m % bm == 0 and n % bn == 0, (x.shape, (bm, bn))
@@ -49,6 +52,6 @@ def value_histogram_pallas(x: jnp.ndarray, num_bins: int, *,
         in_specs=[pl.BlockSpec((bm, bn), lambda i, j: (i, j))],
         out_specs=pl.BlockSpec((1, num_bins), lambda i, j: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((1, num_bins), jnp.int32),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(x)
     return out[0]

@@ -32,6 +32,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ..device import resolve_interpret
+
 __all__ = [
     "Semiring", "semiring_matmul_pallas", "semiring_matmul_batched_pallas",
     "frontier_step_pallas", "frontier_step_batched_pallas",
@@ -117,6 +119,37 @@ class Semiring:
 
 # -- kernel bodies ------------------------------------------------------------
 
+def _as_f32(x):
+    """Cast a block to f32 in-register. Mosaic has no unsigned -> float
+    cast, so uint8 panels and uint32 frontiers go through int32 — exact,
+    since {0,1} cells and counts clamped at MULT_SAT sit far below 2**31."""
+    if jnp.issubdtype(x.dtype, jnp.unsignedinteger):
+        x = x.astype(jnp.int32)
+    return x.astype(jnp.float32)
+
+
+def _from_f32(x, dtype):
+    """Store-side inverse of :func:`_as_f32` (f32 -> uint goes via int32)."""
+    if jnp.issubdtype(dtype, jnp.unsignedinteger):
+        x = x.astype(jnp.int32)
+    return x.astype(dtype)
+
+
+def _unreached(d):
+    """DIST_UNREACHED mask of an int16 dist block; v5e compares no 16-bit
+    integers, so the block widens to int32 first."""
+    return d.astype(jnp.int32) == DIST_UNREACHED
+
+
+def _count_dot(a, b):
+    """The one counting product of the MXU kernels: f32 accumulation at
+    HIGHEST precision, so integer counts stay exact below 2**24 (a
+    single-pass bf16 product would round every count above 256)."""
+    return jax.lax.dot(_as_f32(a), _as_f32(b),
+                       precision=jax.lax.Precision.HIGHEST,
+                       preferred_element_type=jnp.float32)
+
+
 def _vpu_block(sr: Semiring, a, b, acc, sub_k: int):
     """Shared VPU math: semiring product-accumulate of one (bm, bk) x
     (bk, bn) block pair onto ``acc``, K unrolled in sub_k slabs."""
@@ -166,14 +199,11 @@ def _mxu_kernel(a_ref, b_ref, o_ref, acc_ref, *, sr: Semiring, k_blocks: int):
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    acc_ref[...] += jax.lax.dot(
-        a_ref[...].astype(jnp.float32), b_ref[...].astype(jnp.float32),
-        preferred_element_type=jnp.float32,
-    )
+    acc_ref[...] += _count_dot(a_ref[...], b_ref[...])
 
     @pl.when(pl.program_id(2) == k_blocks - 1)
     def _epilogue():
-        o_ref[...] = sr.epilogue(acc_ref[...]).astype(o_ref.dtype)
+        o_ref[...] = _from_f32(sr.epilogue(acc_ref[...]), o_ref.dtype)
 
 
 def _vpu_kernel_batched(*refs, sr: Semiring, sub_k: int):
@@ -205,14 +235,11 @@ def _mxu_kernel_batched(a_ref, b_ref, o_ref, acc_ref, *, sr: Semiring,
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    acc_ref[...] += jax.lax.dot(
-        a_ref[0].astype(jnp.float32), b_ref[0].astype(jnp.float32),
-        preferred_element_type=jnp.float32,
-    )
+    acc_ref[...] += _count_dot(a_ref[0], b_ref[0])
 
     @pl.when(pl.program_id(3) == k_blocks - 1)
     def _epilogue():
-        o_ref[...] = sr.epilogue(acc_ref[...]).astype(o_ref.dtype)[None]
+        o_ref[...] = _from_f32(sr.epilogue(acc_ref[...]), o_ref.dtype)[None]
 
 
 def _frontier_kernel(f_ref, a_ref, d_ref, o_ref, acc_ref, *, k_blocks: int):
@@ -229,16 +256,13 @@ def _frontier_kernel(f_ref, a_ref, d_ref, o_ref, acc_ref, *, k_blocks: int):
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    acc_ref[...] += jax.lax.dot(
-        f_ref[...], a_ref[...],
-        preferred_element_type=jnp.float32,
-    )
+    acc_ref[...] += _count_dot(f_ref[...], a_ref[...])
 
     @pl.when(pl.program_id(2) == k_blocks - 1)
     def _epilogue():
         acc = acc_ref[...]
         new = (acc > 0.0) & (d_ref[...] == jnp.inf)
-        o_ref[...] = jnp.where(new, acc, 0.0).astype(o_ref.dtype)
+        o_ref[...] = _from_f32(jnp.where(new, acc, 0.0), o_ref.dtype)
 
 
 def _frontier_kernel_batched(f_ref, a_ref, d_ref, o_ref, acc_ref, *,
@@ -247,16 +271,13 @@ def _frontier_kernel_batched(f_ref, a_ref, d_ref, o_ref, acc_ref, *,
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    acc_ref[...] += jax.lax.dot(
-        f_ref[0], a_ref[0],
-        preferred_element_type=jnp.float32,
-    )
+    acc_ref[...] += _count_dot(f_ref[0], a_ref[0])
 
     @pl.when(pl.program_id(3) == k_blocks - 1)
     def _epilogue():
         acc = acc_ref[...]
         new = (acc > 0.0) & (d_ref[0] == jnp.inf)
-        o_ref[...] = jnp.where(new, acc, 0.0).astype(o_ref.dtype)[None]
+        o_ref[...] = _from_f32(jnp.where(new, acc, 0.0), o_ref.dtype)[None]
 
 
 def _frontier_kernel_packed(f_ref, a_ref, d_ref, o_ref, acc_ref, *,
@@ -276,17 +297,14 @@ def _frontier_kernel_packed(f_ref, a_ref, d_ref, o_ref, acc_ref, *,
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    acc_ref[...] += jax.lax.dot(
-        f_ref[...].astype(jnp.float32), a_ref[...].astype(jnp.float32),
-        preferred_element_type=jnp.float32,
-    )
+    acc_ref[...] += _count_dot(f_ref[...], a_ref[...])
 
     @pl.when(pl.program_id(2) == k_blocks - 1)
     def _epilogue():
         acc = acc_ref[...]
-        new = (acc > 0.0) & (d_ref[...] == DIST_UNREACHED)
+        new = (acc > 0.0) & _unreached(d_ref[...])
         sat = jnp.minimum(acc, float(MULT_SAT))
-        o_ref[...] = jnp.where(new, sat, 0.0).astype(o_ref.dtype)
+        o_ref[...] = _from_f32(jnp.where(new, sat, 0.0), o_ref.dtype)
 
 
 def _frontier_kernel_packed_batched(f_ref, a_ref, d_ref, o_ref, acc_ref, *,
@@ -295,22 +313,19 @@ def _frontier_kernel_packed_batched(f_ref, a_ref, d_ref, o_ref, acc_ref, *,
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    acc_ref[...] += jax.lax.dot(
-        f_ref[0].astype(jnp.float32), a_ref[0].astype(jnp.float32),
-        preferred_element_type=jnp.float32,
-    )
+    acc_ref[...] += _count_dot(f_ref[0], a_ref[0])
 
     @pl.when(pl.program_id(3) == k_blocks - 1)
     def _epilogue():
         acc = acc_ref[...]
-        new = (acc > 0.0) & (d_ref[0] == DIST_UNREACHED)
+        new = (acc > 0.0) & _unreached(d_ref[0])
         sat = jnp.minimum(acc, float(MULT_SAT))
-        o_ref[...] = jnp.where(new, sat, 0.0).astype(o_ref.dtype)[None]
+        o_ref[...] = _from_f32(jnp.where(new, sat, 0.0), o_ref.dtype)[None]
 
 
 def frontier_step_pallas(f: jnp.ndarray, a: jnp.ndarray, d: jnp.ndarray, *,
                          bm: int = 128, bn: int = 128, bk: int = 128,
-                         interpret: bool = True) -> jnp.ndarray:
+                         interpret: Optional[bool] = None) -> jnp.ndarray:
     """One fused wavefront step: ``where((F@A > 0) & (D == inf), F@A, 0)``.
 
     ``f`` is the (M, K) level-k multiplicity frontier, ``a`` the (K, N)
@@ -336,14 +351,14 @@ def frontier_step_pallas(f: jnp.ndarray, a: jnp.ndarray, d: jnp.ndarray, *,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(f, a, d)
 
 
 def frontier_step_batched_pallas(f: jnp.ndarray, a: jnp.ndarray,
                                  d: jnp.ndarray, *,
                                  bm: int = 128, bn: int = 128, bk: int = 128,
-                                 interpret: bool = True) -> jnp.ndarray:
+                                 interpret: Optional[bool] = None) -> jnp.ndarray:
     """Stacked fused wavefront step over a leading batch axis (B, M, N)."""
     nb, m, k = f.shape
     nb2, k2, n = a.shape
@@ -363,14 +378,14 @@ def frontier_step_batched_pallas(f: jnp.ndarray, a: jnp.ndarray,
         out_specs=pl.BlockSpec((1, bm, bn), lambda bb, i, j, kk: (bb, i, j)),
         out_shape=jax.ShapeDtypeStruct((nb, m, n), jnp.float32),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(f, a, d)
 
 
 def frontier_step_packed_pallas(f: jnp.ndarray, a: jnp.ndarray,
                                 d: jnp.ndarray, *,
                                 bm: int = 128, bn: int = 128, bk: int = 128,
-                                interpret: bool = True) -> jnp.ndarray:
+                                interpret: Optional[bool] = None) -> jnp.ndarray:
     """Packed fused wavefront step over narrow cells.
 
     ``f`` is the (M, K) uint32 multiplicity frontier, ``a`` the (K, N)
@@ -399,7 +414,7 @@ def frontier_step_packed_pallas(f: jnp.ndarray, a: jnp.ndarray,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), MULT_DTYPE),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(f, a, d)
 
 
@@ -407,7 +422,7 @@ def frontier_step_packed_batched_pallas(f: jnp.ndarray, a: jnp.ndarray,
                                         d: jnp.ndarray, *,
                                         bm: int = 128, bn: int = 128,
                                         bk: int = 128,
-                                        interpret: bool = True) -> jnp.ndarray:
+                                        interpret: Optional[bool] = None) -> jnp.ndarray:
     """Stacked packed wavefront step over a leading batch axis."""
     nb, m, k = f.shape
     nb2, k2, n = a.shape
@@ -428,7 +443,7 @@ def frontier_step_packed_batched_pallas(f: jnp.ndarray, a: jnp.ndarray,
         out_specs=pl.BlockSpec((1, bm, bn), lambda bb, i, j, kk: (bb, i, j)),
         out_shape=jax.ShapeDtypeStruct((nb, m, n), MULT_DTYPE),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(f, a, d)
 
 
@@ -436,13 +451,13 @@ def frontier_step_packed_batched_pallas(f: jnp.ndarray, a: jnp.ndarray,
 
 def semiring_matmul_pallas(sr: Semiring, a: Fields, b: Fields, *,
                            bm: int = 128, bn: int = 128, bk: int = 128,
-                           sub_k: int = 8, interpret: bool = True,
+                           sub_k: int = 8, interpret: Optional[bool] = None,
                            out_dtype=None) -> Fields:
     """Blocked (M, K) x (K, N) product over ``sr``; returns one array per field.
 
     M, N, K must divide into blocks (use `ops` for auto-padding).
-    ``interpret=True`` executes the kernel body on CPU (this container);
-    on TPU pass interpret=False. ``out_dtype`` (MXU path only) overrides the
+    ``interpret`` defaults to the backend's choice
+    (`repro.device.resolve_interpret`). ``out_dtype`` (MXU path only) overrides the
     output dtype — the packed engines dot uint32 frontiers against uint8
     panels and take the f32 accumulator out directly.
     """
@@ -478,14 +493,14 @@ def semiring_matmul_pallas(sr: Semiring, a: Fields, b: Fields, *,
         out_specs=o_spec if nf == 1 else [o_spec] * nf,
         out_shape=out_shape[0] if nf == 1 else out_shape,
         scratch_shapes=scratch,
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(*a, *b)
     return (out,) if nf == 1 else tuple(out)
 
 
 def semiring_matmul_batched_pallas(sr: Semiring, a: Fields, b: Fields, *,
                                    bm: int = 128, bn: int = 128, bk: int = 128,
-                                   sub_k: int = 8, interpret: bool = True,
+                                   sub_k: int = 8, interpret: Optional[bool] = None,
                                    out_dtype=None) -> Fields:
     """Batched (B, M, K) x (B, K, N) product over ``sr`` — one kernel launch
     for a whole stack of independent problems (the equal-cost sweep driver's
@@ -525,7 +540,7 @@ def semiring_matmul_batched_pallas(sr: Semiring, a: Fields, b: Fields, *,
         out_specs=o_spec if nf == 1 else [o_spec] * nf,
         out_shape=out_shape[0] if nf == 1 else out_shape,
         scratch_shapes=scratch,
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(*a, *b)
     return (out,) if nf == 1 else tuple(out)
 

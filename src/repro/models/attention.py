@@ -182,7 +182,6 @@ def _decode_attention_sharded(p, q, k, v, cache, cache_pos, cfg, plan):
     baseline per-token all-gather of the cache (jamba decode_32k: 8.6 GB
     gathered per token) with one psum of (B, H, D) partials.
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     mesh = plan.mesh
@@ -239,12 +238,12 @@ def _decode_attention_sharded(p, q, k, v, cache, cache_pos, cfg, plan):
     cache_specs = {
         name: P(bax, ax, None, None) for name in cache
     }
-    fn = shard_map(
+    fn = jax.shard_map(
         local_fn, mesh=mesh,
         in_specs=(P(bax, None, None, None), P(bax, None, None, None),
                   P(bax, None, None, None), cache_specs, P()),
         out_specs=(P(bax, None, None, None), cache_specs),
-        check_rep=False,
+        check_vma=False,
     )
     out, new_cache = fn(q, k, v, cache, cache_pos)
     y = jnp.einsum("bshk,hkd->bsd", out, p["wo"])

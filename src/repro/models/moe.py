@@ -29,7 +29,6 @@ from typing import Dict, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from .common import Spec
@@ -230,11 +229,11 @@ def _moe_ep(x, router, wi, wg, wo, cfg, plan):
     else:
         wi_spec = wg_spec = wo_spec = P(None, None, None)
     x_spec = P(dp, ep_axis if seq_split else None, None)
-    fn = shard_map(
+    fn = jax.shard_map(
         local_fn, mesh=mesh,
         in_specs=(x_spec, P(None, None), wi_spec, wg_spec, wo_spec),
         out_specs=(x_spec, P()),
-        check_rep=False,
+        check_vma=False,
     )
     out, aux = fn(x, router, wi, wg, wo)
     return out, aux

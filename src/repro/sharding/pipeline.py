@@ -25,7 +25,6 @@ from typing import Any, Callable
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 __all__ = ["pipeline_apply", "bubble_fraction"]
@@ -82,10 +81,10 @@ def pipeline_apply(stage_fn: Callable, stage_params: Any, x: jnp.ndarray,
 
     n_axes = len(x.shape)
     p_specs = jax.tree.map(lambda _: P(axis), stage_params)
-    fn = shard_map(
+    fn = jax.shard_map(
         local, mesh=mesh,
         in_specs=(p_specs, P(*([None] * n_axes))),
         out_specs=P(*([None] * n_axes)),
-        check_rep=False,
+        check_vma=False,
     )
     return fn(stage_params, x)

@@ -20,6 +20,7 @@ import numpy as np
 import pytest
 
 import jax
+import jax.extend.core as jex_core
 import jax.numpy as jnp
 
 from repro.core import topology as T
@@ -185,9 +186,9 @@ def _collect(jaxpr, prims):
 
 
 def _subjaxprs(val):
-    if isinstance(val, jax.core.ClosedJaxpr):
+    if isinstance(val, jex_core.ClosedJaxpr):
         yield val.jaxpr
-    elif isinstance(val, jax.core.Jaxpr):
+    elif isinstance(val, jex_core.Jaxpr):
         yield val
     elif isinstance(val, (list, tuple)):
         for item in val:
@@ -252,13 +253,13 @@ def test_widest_divisor_block_any_multiplier():
 
 def test_tiled_explicit_non_power_of_two_block():
     # explicit block= that is a 128-multiple but not a power of two
-    # (640 = 5 x 128 divides pc): bit-equal to the default grid
-    g = T.make("jellyfish", n=600, r=10, seed=2)
+    # (384 = 3 x 128 divides pc = 768): bit-equal to the default grid
+    g = T.make("jellyfish", n=700, r=10, seed=2)
     want_d, want_m = WF.wavefront_dist_mult(g.adjacency_dense(np.float32))
     from repro.kernels.semiring import DIST_UNREACHED
 
     for packed in (False, True):
-        d, m = D.tiled_dist_mult(g, tile_rows=600, packed=packed, block=640)
+        d, m = D.tiled_dist_mult(g, tile_rows=700, packed=packed, block=384)
         if packed:
             d = np.where(d == DIST_UNREACHED, np.inf, d.astype(np.float32))
             m = m.astype(np.float32)
@@ -464,8 +465,7 @@ def test_32k_composed_packed_under_memory_budget():
     out = subprocess.run(
         [sys.executable, "-m", "repro.core.analysis.distributed",
          "--routers", "32768", "--degree", "16", "--tile-rows", "128",
-         "--sources", "64", "--packed", "--shards", "8", "--check", "2",
-         "--block", "4096"],
+         "--sources", "64", "--packed", "--shards", "8", "--check", "2"],
         capture_output=True, text=True, timeout=5400, check=True, env=env,
         cwd=str(__import__("pathlib").Path(__file__).resolve().parents[1]))
     assert "oracle spot-check OK" in out.stdout, out.stdout + out.stderr

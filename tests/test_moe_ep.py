@@ -18,11 +18,8 @@ SCRIPT = textwrap.dedent("""
     from repro.models.common import init_params
     from repro.sharding import activation_ctx, make_plan
 
-    try:  # axis_types landed after jax 0.4.x; EP needs neither
-        mesh = jax.make_mesh((2, 4), ("data", "model"),
-                             axis_types=(jax.sharding.AxisType.Auto,) * 2)
-    except (AttributeError, TypeError):
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = jax.make_mesh((2, 4), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
     for arch, over in [("granite-moe-1b-a400m", {}),
                        ("granite-moe-3b-a800m", {"n_experts": 6, "top_k": 2})]:
         cfg = get_config(arch).reduced()

@@ -23,11 +23,8 @@ SCRIPT = textwrap.dedent("""
     import jax, jax.numpy as jnp, numpy as np
     from repro.sharding.pipeline import pipeline_apply
 
-    try:  # axis_types landed after jax 0.4.x; the schedule needs neither
-        mesh = jax.make_mesh((4, 2), ("pod", "data"),
-                             axis_types=(jax.sharding.AxisType.Auto,) * 2)
-    except (AttributeError, TypeError):
-        mesh = jax.make_mesh((4, 2), ("pod", "data"))
+    mesh = jax.make_mesh((4, 2), ("pod", "data"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
     S, M, MB, D = 4, 6, 3, 16
     key = jax.random.PRNGKey(0)
     w = jax.random.normal(key, (S, D, D)) * 0.3

@@ -85,11 +85,8 @@ def test_checkpoint_elastic_restore_new_sharding(tmp_path):
     """Restore under a different sharding (the re-mesh path)."""
     t = _tree()
     save(tmp_path, 1, t)
-    try:  # axis_types landed after jax 0.4.x; the restore path needs neither
-        mesh = jax.make_mesh((1,), ("data",),
-                             axis_types=(jax.sharding.AxisType.Auto,))
-    except (AttributeError, TypeError):
-        mesh = jax.make_mesh((1,), ("data",))
+    mesh = jax.make_mesh((1,), ("data",),
+                         axis_types=(jax.sharding.AxisType.Auto,))
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     sh = {"a": NamedSharding(mesh, P()), "b": {"c": NamedSharding(mesh, P())}}

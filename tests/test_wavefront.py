@@ -11,6 +11,7 @@ import numpy as np
 import pytest
 
 import jax
+import jax.extend.core as jex_core
 import jax.numpy as jnp
 
 from repro.core import topology as T
@@ -129,9 +130,9 @@ def _collect_primitives(jaxpr, prims):
 
 
 def _subjaxprs(val):
-    if isinstance(val, jax.core.ClosedJaxpr):
+    if isinstance(val, jex_core.ClosedJaxpr):
         yield val.jaxpr
-    elif isinstance(val, jax.core.Jaxpr):
+    elif isinstance(val, jex_core.Jaxpr):
         yield val
     elif isinstance(val, (list, tuple)):
         for item in val:
