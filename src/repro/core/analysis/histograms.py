@@ -10,6 +10,8 @@ from typing import List
 import numpy as np
 import jax.numpy as jnp
 
+from ... import transfers
+
 __all__ = ["path_length_histogram"]
 
 
@@ -19,9 +21,11 @@ def path_length_histogram(dist: np.ndarray, max_len: int = 64,
     if use_kernel:
         from ... import kernels
 
-        d = jnp.asarray(dist, jnp.float32)
+        d = transfers.upload(dist, "histograms", "histogram_dist",
+                             jnp.float32)
         counts = kernels.ops.value_histogram(d, num_bins=max_len + 1)
-        counts = np.asarray(counts)
+        counts = transfers.download(transfers.wait(counts, "histograms"),
+                                    "histograms", "histogram_counts")
     else:
         finite = dist[np.isfinite(dist)].astype(np.int64)
         counts = np.bincount(finite, minlength=max_len + 1)[: max_len + 1]

@@ -122,9 +122,10 @@ class AnalysisEngine:
                 else:
                     from .distributed import sharded_dist_mult
 
+                    with obs.span("distances.host"):
+                        adj = self.g.adjacency_dense(np.float32)
                     # mesh=None degrades to the single-device wavefront
-                    dist, mult = sharded_dist_mult(
-                        self.g.adjacency_dense(np.float32), mesh=plan.mesh)
+                    dist, mult = sharded_dist_mult(adj, mesh=plan.mesh)
                 self._cache["dist"], self._cache["mult"] = dist, mult
             elif self.exact:
                 self._cache["dist"] = apsp_dense(self.g, use_kernel=False)
@@ -202,21 +203,24 @@ class AnalysisEngine:
 
             dist = self.distances()
             mult = self.shortest_path_mult()
-            adj = self.g.adjacency_dense(np.float64)
+            with obs.span("ecmp.host"):
+                adj = self.g.adjacency_dense(np.float64)
             loads = ecmp_all_pairs_loads(dist, mult, adj,
                                          use_kernel=self.use_kernel,
                                          mesh=self._resolved_mesh())
-            off = np.isfinite(dist) & (dist > 0)
-            peak = float(loads.max())
-            spec = self.g.meta.get("spec")
-            cost = cost_report(spec) if spec is not None else {}
-            self._cache["comparison"] = {
-                "ecmp_saturation_throughput": 1.0 / peak if peak > 0 else 1.0,
-                "path_multiplicity_mean": (float(mult[off].mean())
-                                           if off.any() else 0.0),
-                "construction_cost": cost.get("cost_total"),
-                "power_w": cost.get("power_total_w"),
-            }
+            with obs.span("ecmp.host"):
+                off = np.isfinite(dist) & (dist > 0)
+                peak = float(loads.max())
+                spec = self.g.meta.get("spec")
+                cost = cost_report(spec) if spec is not None else {}
+                self._cache["comparison"] = {
+                    "ecmp_saturation_throughput": (1.0 / peak if peak > 0
+                                                   else 1.0),
+                    "path_multiplicity_mean": (float(mult[off].mean())
+                                               if off.any() else 0.0),
+                    "construction_cost": cost.get("cost_total"),
+                    "power_w": cost.get("power_total_w"),
+                }
         return self._cache["comparison"]
 
     # -- stage reports (summary dicts) -------------------------------------
@@ -229,14 +233,16 @@ class AnalysisEngine:
         rep: Dict = {}
         if self.exact:
             dist = self.distances()
-            finite = dist[np.isfinite(dist)]
-            rep["diameter"] = int(finite.max())
-            n = self.g.n
-            rep["avg_path_length"] = float(finite.sum() / max(1, n * (n - 1)))
-            off = max(1, n * (n - 1))
-            reached = int((np.isfinite(dist).sum()) - n)   # minus diagonal
-            rep["disconnected_pair_fraction"] = 1.0 - reached / off
-            rep["exact"] = True
+            with obs.span("distances.host"):
+                finite = dist[np.isfinite(dist)]
+                rep["diameter"] = int(finite.max())
+                n = self.g.n
+                rep["avg_path_length"] = float(
+                    finite.sum() / max(1, n * (n - 1)))
+                off = max(1, n * (n - 1))
+                reached = int((np.isfinite(dist).sum()) - n)  # minus diagonal
+                rep["disconnected_pair_fraction"] = 1.0 - reached / off
+                rep["exact"] = True
         else:
             d = self.distances()
             reachable = d[d >= 0]
@@ -251,29 +257,34 @@ class AnalysisEngine:
             return {}
         paths = self.multiplicities()
         dist = self.distances()
-        off = np.isfinite(dist) & (dist > 0)
-        if not off.any():  # no reachable pair (edgeless / single router)
-            return {}
-        mult, p1, p2 = paths["multiplicity"], paths["plus1"], paths["plus2"]
-        return {
-            "path_multiplicity_mean": float(mult[off].mean()),
-            "path_multiplicity_min": int(mult[off].min()),
-            "path_multiplicity_max": int(mult[off].max()),
-            "nonminimal_plus1_mean": float(p1[off].mean()),
-            "nonminimal_plus2_mean": float(p2[off].mean()),
-            "path_counts_exact": bool(paths["exact"]),
-        }
+        with obs.span("slack.host"):
+            off = np.isfinite(dist) & (dist > 0)
+            if not off.any():  # no reachable pair (edgeless / single router)
+                return {}
+            mult, p1, p2 = (paths["multiplicity"], paths["plus1"],
+                            paths["plus2"])
+            return {
+                "path_multiplicity_mean": float(mult[off].mean()),
+                "path_multiplicity_min": int(mult[off].min()),
+                "path_multiplicity_max": int(mult[off].max()),
+                "nonminimal_plus1_mean": float(p1[off].mean()),
+                "nonminimal_plus2_mean": float(p2[off].mean()),
+                "path_counts_exact": bool(paths["exact"]),
+            }
 
     def _report_diversity(self, with_interference: bool = True) -> Dict:
         if not self.exact:
             return {}
         dist = self.distances()
-        rep = {"path_diversity_mean": float(
-            path_diversity(self.g, dist, seed=self.seed).mean())}
+        with obs.span("diversity.host"):
+            rep = {"path_diversity_mean": float(
+                path_diversity(self.g, dist, seed=self.seed).mean())}
         if with_interference:  # interference rides on the mult stage
-            rep.update(edge_interference(
-                self.g, dist, self.multiplicities()["multiplicity"],
-                pairs=self.interference_pairs, seed=self.seed))
+            mult = self.multiplicities()["multiplicity"]
+            with obs.span("diversity.host"):
+                rep.update(edge_interference(
+                    self.g, dist, mult, pairs=self.interference_pairs,
+                    seed=self.seed))
         return rep
 
     def _report_spectral(self) -> Dict:
@@ -319,7 +330,7 @@ class AnalysisEngine:
             raise ValueError(f"unknown stages {sorted(unknown)}")
         rep = dict(self.g.summary())
         with obs.span("analysis.report", cat="analysis",
-                      family=self.g.name, routers=self.g.n,
+                      family=self.g.name, routers=self.g.n, seed=self.seed,
                       stages=",".join(stages), exact=self.exact):
             for stage in self.STAGES:  # canonical order, not input order
                 if stage not in stages:

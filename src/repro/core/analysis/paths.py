@@ -35,6 +35,7 @@ from typing import Dict, Optional, Tuple
 
 import numpy as np
 
+from ... import obs
 from ..graph import Graph
 
 __all__ = [
@@ -224,39 +225,51 @@ def path_counts_with_slack(
     for the f64 numpy path): plus2 is a difference of large counts, so past
     that point it is clamped at zero but can still be off by the rounding.
     """
+    from ..routing.assign import product_names
+
     product = _count_product(use_kernel)
     n = g.n
-    a = g.adjacency_dense(np.float32)
-    deg = g.degrees().astype(np.float32)
-    finite = np.isfinite(dist)
-    diam = int(dist[finite].max()) if finite.any() else 0
+    with obs.span("slack.host"):
+        a = g.adjacency_dense(np.float32)
+        deg = g.degrees().astype(np.float32)
+        finite = np.isfinite(dist)
+        diam = int(dist[finite].max()) if finite.any() else 0
 
-    walks = np.eye(n, dtype=np.float32)       # A^L
-    bounce = np.diag(deg).astype(np.float32)  # T_L = sum_l A^l D A^(L-l)
-    mult = np.where(dist == 0, np.float32(1), np.float32(0))
-    plus1 = np.zeros((n, n), np.float32)
-    plus2 = np.zeros((n, n), np.float32)
-    correction = np.where(dist == 0, bounce, np.float32(0))  # T_d at d = 0
+        walks = np.eye(n, dtype=np.float32)       # A^L
+        bounce = np.diag(deg).astype(np.float32)  # T_L = sum_l A^l D A^(L-l)
+        mult = np.where(dist == 0, np.float32(1), np.float32(0))
+        plus1 = np.zeros((n, n), np.float32)
+        plus2 = np.zeros((n, n), np.float32)
+        correction = np.where(dist == 0, bounce, np.float32(0))  # T_d at d=0
 
     exact_limit = float(2 ** 24 if use_kernel else 2 ** 53)
     exact = True
     for level in range(1, diam + 3):
-        walks = product(walks, a)
-        # T_L = T_(L-1) A + A^L D; the second term is a column scale, no matmul
-        bounce = product(bounce, a) + walks * deg[None, :]
-        exact = exact and walks.max() <= exact_limit and bounce.max() <= exact_limit
-        mult = np.where(dist == level, walks, mult)
-        plus1 = np.where(dist == level - 1, walks, plus1)
-        plus2 = np.where(dist == level - 2, walks, plus2)
-        correction = np.where(dist == level, bounce, correction)
+        # each product uploads both operands, the adjacency included
+        with product_names("slack", "slack_walks", "slack_adjacency",
+                           "slack_walks"):
+            walks = product(walks, a)
+        with product_names("slack", "slack_bounce", "slack_adjacency",
+                           "slack_bounce"):
+            bounce_a = product(bounce, a)
+        with obs.span("slack.host"):
+            # T_L = T_(L-1) A + A^L D; the second term is a column scale
+            bounce = bounce_a + walks * deg[None, :]
+            exact = (exact and walks.max() <= exact_limit
+                     and bounce.max() <= exact_limit)
+            mult = np.where(dist == level, walks, mult)
+            plus1 = np.where(dist == level - 1, walks, plus1)
+            plus2 = np.where(dist == level - 2, walks, plus2)
+            correction = np.where(dist == level, bounce, correction)
 
-    d0 = np.where(finite, dist, 0.0).astype(np.float32)
-    # difference of large counts: clamp the rounding's negative excursions
-    plus2 = np.maximum(plus2 - correction + d0 * mult, 0.0)
-    # unreachable pairs carry no paths at any slack
-    mult = np.where(finite, mult, 0.0)
-    plus1 = np.where(finite, plus1, 0.0)
-    plus2 = np.where(finite & (dist > 0), plus2, 0.0)
+    with obs.span("slack.host"):
+        d0 = np.where(finite, dist, 0.0).astype(np.float32)
+        # difference of large counts: clamp the rounding's negative excursions
+        plus2 = np.maximum(plus2 - correction + d0 * mult, 0.0)
+        # unreachable pairs carry no paths at any slack
+        mult = np.where(finite, mult, 0.0)
+        plus1 = np.where(finite, plus1, 0.0)
+        plus2 = np.where(finite & (dist > 0), plus2, 0.0)
     return {"multiplicity": mult, "plus1": plus1, "plus2": plus2,
             "exact": exact}
 

@@ -17,6 +17,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from ... import obs, transfers
 from ..graph import Graph
 
 __all__ = ["fiedler_value", "spectral_bounds"]
@@ -29,10 +30,16 @@ def _laplacian_dense(g: Graph) -> np.ndarray:
     return lap
 
 
+def _laplacian_device(g: Graph) -> jnp.ndarray:
+    with obs.span("spectral.host"):
+        lap = _laplacian_dense(g)
+    return transfers.upload(lap, "spectral", "laplacian")
+
+
 def fiedler_value(g: Graph, iters: int = 300, seed: int = 0,
                   return_vector: bool = False):
     """lambda_2 of the unnormalized Laplacian via shifted power iteration."""
-    lap = jnp.asarray(_laplacian_dense(g))
+    lap = _laplacian_device(g)
     n = g.n
     deg_max = float(jnp.max(jnp.diag(lap)))
     c = 2.0 * deg_max + 1.0
@@ -59,7 +66,7 @@ def fiedler_value(g: Graph, iters: int = 300, seed: int = 0,
 
 
 def lambda_max(g: Graph, iters: int = 200, seed: int = 1) -> float:
-    lap = jnp.asarray(_laplacian_dense(g))
+    lap = _laplacian_device(g)
     v = jax.random.normal(jax.random.PRNGKey(seed), (g.n,), jnp.float32)
 
     def step(v, _):

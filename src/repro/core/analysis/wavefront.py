@@ -47,7 +47,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from ... import obs
+from ... import obs, transfers
 from ...device import resolve_interpret
 
 __all__ = ["wavefront_dist_mult", "dist_mult_device", "ecmp_loads_device",
@@ -325,10 +325,12 @@ def wavefront_dist_mult(adj: np.ndarray, block: Optional[int] = None,
     with obs.span("wavefront.dist_mult", routers=n, padded=p, block=block,
                   batched=batched, packed=packed) as sp:
         dtype = np.uint8 if packed else np.float32
-        padded = pad_operand(adj, p, 0, dtype=dtype)
-        obs.record_h2d(padded.nbytes, "adjacency")
-        out = dist_mult_device(jnp.asarray(padded), block=block,
-                               telemetry=tel, packed=packed)
+        with obs.span("wavefront.host"):
+            padded = pad_operand(adj, p, 0, dtype=dtype)
+        out = dist_mult_device(transfers.upload(padded, "wavefront",
+                                                "adjacency"),
+                               block=block, telemetry=tel, packed=packed)
+        transfers.wait(out, "wavefront")
         if packed:
             dist, mult, sat = out[0], out[1], out[2]
             if tel:
@@ -346,10 +348,11 @@ def wavefront_dist_mult(adj: np.ndarray, block: Optional[int] = None,
         else:
             dist, mult = out
         sl = (Ellipsis, slice(None, n), slice(None, n))
-        mult = np.asarray(mult)[sl]
-        dist = np.asarray(dist)[sl]
-    if not packed:
-        _warn_if_inexact(mult, use_kernel=True)
+        mult = transfers.download(mult, "wavefront", "wavefront_mult")[sl]
+        dist = transfers.download(dist, "wavefront", "wavefront_dist")[sl]
+        if not packed:
+            with obs.span("wavefront.host"):
+                _warn_if_inexact(mult, use_kernel=True)
     return dist, mult
 
 

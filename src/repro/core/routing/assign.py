@@ -37,28 +37,69 @@ use this helper, so their ``*_imbalance`` ratios are directly comparable.
 """
 from __future__ import annotations
 
+import contextlib
+import contextvars
 from typing import Callable, Dict, Iterable, Optional, Sequence, Tuple
 
 import numpy as np
 
+from ... import obs
 from ..graph import Graph
 
 __all__ = ["demand_matrix", "ecmp_link_loads", "ecmp_all_pairs_loads",
            "ecmp_demand_loads", "walk_slack_link_loads",
            "directed_to_link_loads", "link_load_stats", "count_product",
-           "padded_neighbors", "sample_columns", "mask_unreachable_demand"]
+           "product_names", "padded_neighbors", "sample_columns",
+           "mask_unreachable_demand"]
+
+#: (stage, left operand, right operand, product): the names under which a
+#: kernel-path counting product reports its transfers (`repro.transfers`)
+_PRODUCT_NAMES = contextvars.ContextVar(
+    "count_product_names",
+    default=("count", "count_lhs", "count_rhs", "count_out"))
+
+
+def product_names(stage: str, lhs: str, rhs: str, out: str):
+    """Context naming the transfers of the counting products called inside
+    it: spans ``<stage>.h2d`` / ``.wait`` / ``.d2h`` and byte counters
+    ``h2d_bytes.<lhs>``, ``h2d_bytes.<rhs>``, ``d2h_bytes.<out>``. With
+    tracing off it names nothing. A context and not an argument, so that
+    `count_product` keeps the one shape every caller, and every stand-in
+    for it, has: ``count_product(use_kernel)(a, b)``."""
+    if not obs.enabled():
+        return contextlib.nullcontext()
+    return _named_products((stage, lhs, rhs, out))
+
+
+@contextlib.contextmanager
+def _named_products(names):
+    token = _PRODUCT_NAMES.set(names)
+    try:
+        yield
+    finally:
+        _PRODUCT_NAMES.reset(token)
 
 
 def count_product(use_kernel: bool) -> Callable[[np.ndarray, np.ndarray],
                                                 np.ndarray]:
-    """(+, x) matmul: Pallas COUNTING kernel, or f64 numpy oracle."""
+    """(+, x) matmul: Pallas COUNTING kernel, or f64 numpy oracle.
+
+    The kernel path uploads both operands, waits for the product and
+    downloads it, each through `repro.transfers` under the names
+    `product_names` gives."""
     if use_kernel:
         import jax.numpy as jnp
 
-        from ... import kernels
+        from ... import kernels, transfers
 
-        return lambda a, b: np.asarray(kernels.ops.count_matmul(
-            jnp.asarray(a, jnp.float32), jnp.asarray(b, jnp.float32)))
+        def product(a, b):
+            stage, lhs, rhs, out = _PRODUCT_NAMES.get()
+            c = kernels.ops.count_matmul(
+                transfers.upload(a, stage, lhs, jnp.float32),
+                transfers.upload(b, stage, rhs, jnp.float32))
+            return transfers.download(transfers.wait(c, stage), stage, out)
+
+        return product
     return lambda a, b: np.asarray(a, np.float64) @ np.asarray(b, np.float64)
 
 
@@ -277,30 +318,39 @@ def _ecmp_all_pairs_device(dist: np.ndarray, mult: np.ndarray,
     source rows (`distributed.ecmp_loads_sharded`); jit reshards the
     replicated uploads onto the mesh per the engine's in_specs.
     """
-    import jax.numpy as jnp
-
+    from ... import transfers
     from ..analysis.wavefront import ecmp_loads_device, pad_block, pad_operand
 
     n = np.asarray(dist).shape[-1]
     batched = np.asarray(dist).ndim == 3
-    if mesh is not None and mesh.size > 1:
+    sharded = mesh is not None and mesh.size > 1
+    if sharded:
         from ..analysis.distributed import (ROW_AXIS, ecmp_loads_sharded,
                                             pad_block_sharded)
 
         p, _, block = pad_block_sharded(n, mesh.shape[ROW_AXIS],
                                         batched=batched)
-        loads = ecmp_loads_sharded(jnp.asarray(pad_operand(dist, p, np.inf)),
-                                   jnp.asarray(pad_operand(mult, p, 0.0)),
-                                   jnp.asarray(pad_operand(adj, p, 0.0)),
-                                   mesh, block=block)
     else:
         p, block = pad_block(n, batched=batched)
-        loads = ecmp_loads_device(jnp.asarray(pad_operand(dist, p, np.inf)),
-                                  jnp.asarray(pad_operand(mult, p, 0.0)),
-                                  jnp.asarray(pad_operand(adj, p, 0.0)),
-                                  block=block)
-    sl = (Ellipsis, slice(None, n), slice(None, n))
-    return np.asarray(loads)[sl].astype(np.float64)
+
+    def operand(x, fill, what):
+        # pad and upload one operand at a time: one padded host copy lives
+        with obs.span("ecmp.host"):
+            x = pad_operand(x, p, fill)
+        return transfers.upload(x, "ecmp", what)
+
+    operands = (operand(dist, np.inf, "ecmp_dist"),
+                operand(mult, 0.0, "ecmp_mult"),
+                operand(adj, 0.0, "ecmp_adjacency"))
+    if sharded:
+        loads = ecmp_loads_sharded(*operands, mesh, block=block)
+    else:
+        loads = ecmp_loads_device(*operands, block=block)
+    loads = transfers.download(transfers.wait(loads, "ecmp"), "ecmp",
+                               "ecmp_loads")
+    with obs.span("ecmp.host"):
+        sl = (Ellipsis, slice(None, n), slice(None, n))
+        return loads[sl].astype(np.float64)
 
 
 def ecmp_demand_loads(dist: np.ndarray, mult: np.ndarray, adj: np.ndarray,

@@ -32,6 +32,7 @@ import dataclasses
 import inspect
 from typing import Callable, Dict, List, Optional
 
+from ... import obs
 from ..graph import Graph
 from .spec import TopologySpec
 
@@ -71,19 +72,22 @@ def _family(name: str) -> Family:
 
 
 def make(name: str, **params) -> Graph:
-    """Build ``name`` and attach its :class:`TopologySpec` to ``meta``."""
+    """Build ``name`` and attach its :class:`TopologySpec` to ``meta``
+    (host work, spanned as ``topology.host`` while tracing)."""
     fam = _family(name)
-    g = fam.build(**params)
-    if fam.spec is not None and "spec" not in g.meta:
-        # drop build-only kwargs (e.g. polarfly's blocked-product `chunk`)
-        # that don't shape the topology; genuine typos still fail in build()
-        accepted = inspect.signature(fam.spec).parameters
-        s = fam.spec(**{k: v for k, v in params.items() if k in accepted})
-        if s.n_routers != g.n:
-            raise RuntimeError(
-                f"{name}: spec says {s.n_routers} routers, generator built "
-                f"{g.n} — closed-form spec drifted from the generator")
-        g.meta["spec"] = s
+    with obs.span("topology.host", cat="topology", family=name):
+        g = fam.build(**params)
+        if fam.spec is not None and "spec" not in g.meta:
+            # drop build-only kwargs (e.g. polarfly's blocked-product `chunk`)
+            # that don't shape the topology; genuine typos still fail in build()
+            accepted = inspect.signature(fam.spec).parameters
+            s = fam.spec(**{k: v for k, v in params.items() if k in accepted})
+            if s.n_routers != g.n:
+                raise RuntimeError(
+                    f"{name}: spec says {s.n_routers} routers, generator "
+                    f"built {g.n} — closed-form spec drifted from the "
+                    f"generator")
+            g.meta["spec"] = s
     return g
 
 

@@ -1,4 +1,4 @@
-"""Counters, gauges, and histograms — the numeric half of observability.
+"""Counters and gauges — the numeric half of observability.
 
 A process-global registry of named meters, stdlib-only and thread-safe,
 plus the three samplers the analysis stack actually needs:
@@ -9,13 +9,15 @@ plus the three samplers the analysis stack actually needs:
 * :func:`device_memory_mb` — jax device allocator stats when the backend
   exposes them (TPU/GPU; interpret-mode CPU reports nothing and the caller
   gets ``None``, never an exception);
-* :func:`record_h2d` — the host->device transfer-byte tap every upload
-  seam calls (`analysis.distributed` panel/adjacency uploads,
+* :func:`record_h2d` / :func:`record_d2h` — the transfer-byte taps every
+  upload and download seam calls (`repro.transfers` at the analysis
+  seams, `analysis.distributed` panel/adjacency uploads,
   `routing.throughput`'s per-round length uploads, the sweep's stacked
-  upload). Counts into the ``h2d_bytes`` counter, accumulates into the
-  innermost live span's ``h2d_bytes`` attribute, and emits a Perfetto
-  counter sample — all gated on tracing being enabled so the hot paths
-  stay untouched otherwise.
+  upload). Each counts into the ``h2d_bytes`` / ``d2h_bytes`` counter and
+  its ``.<what>`` split, accumulates into the innermost live span's
+  attribute of the same name, and emits a Perfetto counter sample — all
+  gated on tracing being enabled so the hot paths stay untouched
+  otherwise.
 
 :func:`snapshot` returns the whole registry as one dict; `trace.export`
 embeds it in the trace file's ``otherData`` and `repro.obs.report` prints
@@ -28,9 +30,9 @@ from typing import Dict, Optional
 
 from . import trace
 
-__all__ = ["Counter", "Gauge", "Histogram", "counter", "gauge", "histogram",
+__all__ = ["Counter", "Gauge", "counter", "gauge",
            "snapshot", "reset", "rss_mb", "peak_rss_mb", "device_memory_mb",
-           "sample_process", "record_h2d"]
+           "sample_process", "record_h2d", "record_d2h"]
 
 
 class Counter:
@@ -74,36 +76,6 @@ class Gauge:
         return {"type": "gauge", "value": self.value, "max": self.max}
 
 
-class Histogram:
-    """Streaming count/sum/min/max/mean (stage latencies, tile levels)."""
-
-    __slots__ = ("name", "count", "total", "min", "max", "_lock")
-
-    def __init__(self, name: str):
-        self.name = name
-        self.count = 0
-        self.total = 0.0
-        self.min = float("inf")
-        self.max = float("-inf")
-        self._lock = threading.Lock()
-
-    def observe(self, value) -> "Histogram":
-        v = float(value)
-        with self._lock:
-            self.count += 1
-            self.total += v
-            self.min = min(self.min, v)
-            self.max = max(self.max, v)
-        return self
-
-    def describe(self) -> Dict:
-        if not self.count:
-            return {"type": "histogram", "count": 0}
-        return {"type": "histogram", "count": self.count,
-                "sum": self.total, "min": self.min, "max": self.max,
-                "mean": self.total / self.count}
-
-
 _REGISTRY: Dict[str, object] = {}
 _REG_LOCK = threading.Lock()
 
@@ -125,10 +97,6 @@ def counter(name: str) -> Counter:
 
 def gauge(name: str) -> Gauge:
     return _get(name, Gauge)
-
-
-def histogram(name: str) -> Histogram:
-    return _get(name, Histogram)
 
 
 def snapshot() -> Dict[str, Dict]:
@@ -205,10 +173,20 @@ def sample_process(prefix: str = "process") -> Dict[str, float]:
 def record_h2d(nbytes: int, what: str = "") -> None:
     """Tap one host->device upload of ``nbytes``. Gated on tracing so the
     upload seams cost a single boolean check when observability is off."""
-    if not trace.enabled():
-        return
-    counter("h2d_bytes").add(int(nbytes))
+    if trace.enabled():
+        _record("h2d_bytes", int(nbytes), what)
+
+
+def record_d2h(nbytes: int, what: str = "") -> None:
+    """Tap one device->host download of ``nbytes``; gated like
+    :func:`record_h2d`."""
+    if trace.enabled():
+        _record("d2h_bytes", int(nbytes), what)
+
+
+def _record(key: str, nbytes: int, what: str) -> None:
+    counter(key).add(nbytes)
     if what:
-        counter(f"h2d_bytes.{what}").add(int(nbytes))
-    trace.current().inc("h2d_bytes", int(nbytes))
-    trace.counter_sample("h2d_bytes", total=counter("h2d_bytes").value)
+        counter(f"{key}.{what}").add(nbytes)
+    trace.current().inc(key, nbytes)
+    trace.counter_sample(key, total=counter(key).value)

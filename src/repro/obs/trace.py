@@ -14,7 +14,13 @@ as-is. The design constraints, in order:
 * **thread-safe** — span stacks are thread-local (each thread is its own
   Perfetto track via ``tid``); the completed-event list is append-only
   under one lock.
-* **zero dependencies** — stdlib only; jax is never imported here.
+* **zero dependencies** — stdlib only; jax is never imported here. Where
+  the process has already imported jax, :func:`enable` also (a) enters a
+  ``jax.profiler.TraceAnnotation`` of each span's name, so the spans land
+  in the profiler's host plane on the device trace's clock, and (b)
+  listens to JAX's tracing, lowering and compile events, adding their
+  seconds to the innermost live span's ``jit_s`` attribute (and each
+  backend compile, a persistent-cache load included, to ``compiles``).
 
 Kill switch: :func:`enable` / :func:`disable`, or the ``REPRO_TRACE``
 environment variable — ``1`` enables for the process, any other non-empty
@@ -36,18 +42,28 @@ trace), and :func:`export` writes the Chrome JSON.
 from __future__ import annotations
 
 import atexit
-import functools
 import json
 import os
+import sys
 import threading
 import time
 from typing import Any, Dict, List, Optional
 
 __all__ = ["Tracer", "enabled", "enable", "disable", "reset", "span",
-           "current", "traced", "instant", "counter_sample", "log",
+           "current", "instant", "counter_sample", "log",
            "export", "events", "span_summary", "ENV_FLAG"]
 
 ENV_FLAG = "REPRO_TRACE"
+
+#: JAX's monitoring events whose seconds go to a span's ``jit_s``: tracing
+#: to a jaxpr, lowering to MLIR, and the backend compile (which JAX also
+#: reports for a program loaded from the persistent compile cache)
+JIT_EVENTS = ("/jax/core/compile/jaxpr_trace_duration",
+              "/jax/core/compile/jaxpr_to_mlir_module_duration",
+              "/jax/core/compile/backend_compile_duration")
+_BACKEND_COMPILE = JIT_EVENTS[2]
+#: how many of a thread's last JIT intervals are kept to discount nesting
+_JIT_MEMORY = 64
 
 
 def _json_safe(v):
@@ -95,7 +111,7 @@ NULL_SPAN = _NullSpan()
 class _Span:
     """One live span: created by :meth:`Tracer.span`, closed on __exit__."""
 
-    __slots__ = ("tracer", "name", "cat", "args", "ts", "dur", "tid")
+    __slots__ = ("tracer", "name", "cat", "args", "ts", "dur", "tid", "mark")
 
     def __init__(self, tracer: "Tracer", name: str, cat: str,
                  args: Dict[str, Any]):
@@ -106,6 +122,7 @@ class _Span:
         self.ts = 0
         self.dur = 0
         self.tid = 0
+        self.mark = None
 
     def set(self, **attrs) -> "_Span":
         self.args.update(attrs)
@@ -122,11 +139,16 @@ class _Span:
         t = self.tracer
         self.tid = threading.get_ident()
         t._stack().append(self)
+        if t._annotate is not None:
+            self.mark = t._annotate(self.name)
+            self.mark.__enter__()
         self.ts = time.perf_counter_ns()
         return self
 
     def __exit__(self, exc_type, exc, tb):
         self.dur = time.perf_counter_ns() - self.ts
+        if self.mark is not None:
+            self.mark.__exit__(exc_type, exc, tb)
         stack = self.tracer._stack()
         if stack and stack[-1] is self:
             stack.pop()
@@ -152,6 +174,9 @@ class Tracer:
         self._events: List[Dict] = []
         self._lock = threading.Lock()
         self._local = threading.local()
+        #: jax.profiler.TraceAnnotation once jax is hooked, else None
+        self._annotate = None
+        self._jax_hooked = False
 
     # -- internals ---------------------------------------------------------
 
@@ -160,6 +185,47 @@ class Tracer:
         if stack is None:
             stack = self._local.stack = []
         return stack
+
+    def _hook_jax(self) -> None:
+        """Annotate spans in the profiler's trace and listen to JAX's JIT
+        events — once per process, and only where jax is already
+        imported (this module never imports it)."""
+        if self._jax_hooked or "jax" not in sys.modules:
+            return
+        from jax import monitoring, profiler
+
+        monitoring.register_event_duration_secs_listener(self._on_jax_event)
+        self._annotate = profiler.TraceAnnotation
+        self._jax_hooked = True
+
+    def _on_jax_event(self, event: str, duration: float, **_) -> None:
+        if not self.enabled or event not in JIT_EVENTS:
+            return
+        sp = self.current()
+        if not sp:
+            return
+        end = time.perf_counter()
+        sp.inc("jit_s", self._fresh_jit_seconds(end - duration, end))
+        if event == _BACKEND_COMPILE:
+            sp.inc("compiles", 1)
+
+    def _fresh_jit_seconds(self, start: float, end: float) -> float:
+        """Seconds of [start, end] that no earlier JIT event of this thread
+        covered. JAX reports nested work under events of its own (a jit
+        traced inside another's trace, primitives traced while lowering),
+        and each event arrives as it ends, so the earlier events that
+        overlap this one are the ones it contains."""
+        seen = getattr(self._local, "jit", None)
+        if seen is None:
+            seen = self._local.jit = []
+        covered, lo = 0.0, start
+        while seen and seen[-1][1] > start:
+            s, e = seen.pop()
+            covered += max(0.0, min(e, end) - max(s, start))
+            lo = min(lo, s)
+        seen.append((lo, end))
+        del seen[:-_JIT_MEMORY]
+        return max(0.0, end - start - covered)
 
     def _emit(self, event: Dict) -> None:
         with self._lock:
@@ -170,6 +236,8 @@ class Tracer:
     def span(self, name: str, cat: str = "analysis", **args):
         if not self.enabled:
             return NULL_SPAN
+        if not self._jax_hooked:  # jax imported after enable()
+            self._hook_jax()
         return _Span(self, name, cat, args)
 
     def current(self):
@@ -258,6 +326,7 @@ def enabled() -> bool:
 
 def enable() -> Tracer:
     _TRACER.enabled = True
+    _TRACER._hook_jax()
     return _TRACER
 
 
@@ -296,25 +365,6 @@ def span_summary() -> Dict[str, Dict[str, float]]:
 
 def export(path: Optional[str] = None) -> Dict:
     return _TRACER.export(path)
-
-
-def traced(name: Optional[str] = None, cat: str = "analysis"):
-    """Decorator form of :func:`span` (span name defaults to the function's
-    qualified name)."""
-
-    def deco(fn):
-        label = name or fn.__qualname__
-
-        @functools.wraps(fn)
-        def wrapper(*a, **kw):
-            if not _TRACER.enabled:
-                return fn(*a, **kw)
-            with _TRACER.span(label, cat):
-                return fn(*a, **kw)
-
-        return wrapper
-
-    return deco
 
 
 def log(event: str, **fields) -> None:

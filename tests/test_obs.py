@@ -101,21 +101,6 @@ def test_span_summary_aggregates_by_name():
     assert summary["stage.a"]["total_ms"] >= 0.0
 
 
-def test_traced_decorator():
-    calls = []
-
-    @obs.traced("deco.span")
-    def fn(x):
-        calls.append(x)
-        return x + 1
-
-    assert fn(1) == 2  # disabled: no span, still runs
-    assert obs.events() == []
-    obs.enable()
-    assert fn(2) == 3
-    assert [ev["name"] for ev in obs.events()] == ["deco.span"]
-
-
 def test_threaded_spans_land_on_their_own_tracks():
     obs.enable()
     barrier = threading.Barrier(4)  # all alive at once: distinct idents
@@ -169,12 +154,9 @@ def test_meter_registry_and_types():
     obs.counter("c").add().add(2)
     obs.gauge("g").set(5.0)
     obs.gauge("g").set(2.0)
-    obs.histogram("h").observe(1.0)
-    obs.histogram("h").observe(3.0)
     snap = obs.snapshot()
     assert snap["c"] == {"type": "counter", "value": 3}
     assert snap["g"] == {"type": "gauge", "value": 2.0, "max": 5.0}
-    assert snap["h"]["count"] == 2 and snap["h"]["mean"] == 2.0
     with pytest.raises(TypeError):
         obs.gauge("c")  # name already registered as a counter
 
@@ -200,6 +182,81 @@ def test_record_h2d_gated_on_tracing():
     # the Perfetto counter track got samples too
     assert any(ev["ph"] == "C" and ev["name"] == "h2d_bytes"
                for ev in obs.events())
+
+
+def test_record_d2h_gated_on_tracing():
+    obs.record_d2h(4096, "download")  # disabled: must not even register
+    assert "d2h_bytes" not in obs.snapshot()
+    obs.enable()
+    with obs.span("stage") as sp:
+        with obs.span("stage.d2h") as leaf:
+            obs.record_d2h(4096, "download")
+        obs.record_d2h(1024)
+    snap = obs.snapshot()
+    assert snap["d2h_bytes"]["value"] == 5120
+    assert snap["d2h_bytes.download"]["value"] == 4096
+    assert "h2d_bytes" not in snap
+    # bytes land on the innermost live span only
+    assert leaf.args["d2h_bytes"] == 4096 and sp.args["d2h_bytes"] == 1024
+    assert any(ev["ph"] == "C" and ev["name"] == "d2h_bytes"
+               for ev in obs.events())
+
+
+# -- JAX hooks: JIT seconds and profiler annotations -----------------------------
+
+def test_jit_listener_charges_the_innermost_span():
+    import jax
+    import jax.numpy as jnp
+
+    x = jnp.arange(11.0)
+
+    @jax.jit
+    def fresh(v):  # a new function: its first call traces and compiles
+        return jnp.sin(v) * 3.0 + 1.0
+
+    obs.enable()
+    with obs.span("outer") as outer:
+        with obs.span("inner") as inner:
+            fresh(x).block_until_ready()
+        fresh(x).block_until_ready()  # cached: no JIT event
+    assert inner.args["jit_s"] > 0 and inner.args["compiles"] >= 1
+    assert "jit_s" not in outer.args and "compiles" not in outer.args
+    assert inner.args["jit_s"] <= inner.dur / 1e9
+
+    # off: a compile inside a span left open adds nothing to it
+    with obs.span("open") as sp:
+        obs.disable()
+        jax.jit(lambda v: v * 5.0 - 2.0)(x).block_until_ready()
+        obs.enable()
+    assert "jit_s" not in sp.args and "compiles" not in sp.args
+
+
+def test_nested_jit_events_count_once():
+    # JAX reports a jit traced inside another's trace under its own event,
+    # before the outer one; the outer event's seconds then hold the inner
+    tr = obs.Tracer(enabled=True)
+    assert tr._fresh_jit_seconds(1.2, 1.5) == pytest.approx(0.3)
+    assert tr._fresh_jit_seconds(1.0, 3.0) == pytest.approx(1.7)
+    assert tr._fresh_jit_seconds(3.5, 4.0) == pytest.approx(0.5)
+
+
+def test_spans_land_in_the_profiler_trace(tmp_path):
+    import jax
+    import jax.numpy as jnp
+    from jax.profiler import ProfileData
+
+    obs.enable()
+    with jax.profiler.trace(str(tmp_path)):
+        with obs.span("probe.outer"):
+            with obs.span("probe.host"):
+                jnp.ones(5).block_until_ready()
+    (path,) = tmp_path.rglob("*.xplane.pb")
+    data = ProfileData.from_file(str(path))
+    host = {ev.name: ev.duration_ns for plane in data.planes
+            if not plane.name.startswith("/device:")
+            for line in plane.lines for ev in line.events}
+    assert {"probe.outer", "probe.host"} <= set(host)
+    assert host["probe.outer"] >= host["probe.host"]
 
 
 # -- report CLI ---------------------------------------------------------------

@@ -1,0 +1,104 @@
+"""The analysis seams under tracing: each stage of a design point splits
+into leaf spans ``<stage>.host`` / ``.h2d`` / ``.wait`` / ``.d2h``, every
+transfer counts its bytes, and tracing changes no result.
+
+On Slim Fly q=5 (n = 50 routers, padded to p = 128 for the device loops)
+the bytes follow from the operand shapes alone: the wavefront uploads the
+padded float32 adjacency and downloads padded dist and mult; each of the
+8 slack-count products (walks and bounces, levels 1 .. diameter + 2)
+uploads two n x n float32 operands and downloads one; the histogram
+uploads dist and downloads 65 int32 bins; spectral uploads two
+Laplacians; ECMP uploads padded dist, mult and adjacency and downloads the
+padded loads.
+"""
+import numpy as np
+import pytest
+
+from repro import obs
+from repro.core import topology as T
+from repro.core.analysis import AnalysisEngine
+from repro.core.analysis.wavefront import pad_block
+
+F32 = 4
+LEAVES = {
+    None: {"topology.host", "distances.host", "wavefront.host",
+           "wavefront.h2d", "wavefront.wait", "wavefront.d2h", "slack.host",
+           "slack.h2d", "slack.wait", "slack.d2h", "diversity.host",
+           "spectral.host", "spectral.h2d", "histograms.h2d",
+           "histograms.wait", "histograms.d2h"},
+    ("distances", "comparison"): {
+        "topology.host", "distances.host", "wavefront.host", "wavefront.h2d",
+        "wavefront.wait", "wavefront.d2h", "ecmp.host", "ecmp.h2d",
+        "ecmp.wait", "ecmp.d2h"},
+}
+
+
+def _bytes(stages, n, p):
+    """(h2d by name, d2h by name) the stages move at n routers."""
+    nn, pp = n * n * F32, p * p * F32
+    h2d = {"adjacency": pp}
+    d2h = {"wavefront_dist": pp, "wavefront_mult": pp}
+    if stages is None:
+        h2d.update(slack_walks=4 * nn, slack_bounce=4 * nn,
+                   slack_adjacency=8 * nn, histogram_dist=nn,
+                   laplacian=2 * nn)
+        d2h.update(slack_walks=4 * nn, slack_bounce=4 * nn,
+                   histogram_counts=65 * 4)
+    else:
+        h2d.update(ecmp_dist=pp, ecmp_mult=pp, ecmp_adjacency=pp)
+        d2h.update(ecmp_loads=pp)
+    return h2d, d2h
+
+
+@pytest.fixture
+def traced():
+    obs.disable()
+    obs.reset()
+    obs.meters.reset()
+    obs.enable()
+    yield
+    obs.disable()
+    obs.reset()
+    obs.meters.reset()
+
+
+@pytest.mark.parametrize("stages", [None, ("distances", "comparison")])
+def test_report_splits_into_leaf_spans_with_exact_bytes(stages, traced):
+    g = T.make("slimfly", q=5)
+    AnalysisEngine(g, mesh=None, seed=3).report(stages)
+    events = [ev for ev in obs.events() if ev["ph"] == "X"]
+    names = {ev["name"] for ev in events}
+    leaves = {nm for nm in names
+              if nm.rpartition(".")[2] in ("host", "h2d", "d2h", "wait")}
+    assert leaves == LEAVES[stages]
+
+    (report,) = [ev for ev in events if ev["name"] == "analysis.report"]
+    assert report["args"]["seed"] == 3
+    n, p = g.n, pad_block(g.n)[0]
+    h2d, d2h = _bytes(stages, n, p)
+    total = {k: sum(ev["args"].get(k, 0) for ev in events)
+             for k in ("h2d_bytes", "d2h_bytes")}
+    assert total == {"h2d_bytes": sum(h2d.values()),
+                     "d2h_bytes": sum(d2h.values())}
+    snap = obs.snapshot()
+    assert {k: snap[f"h2d_bytes.{k}"]["value"] for k in h2d} == h2d
+    assert {k: snap[f"d2h_bytes.{k}"]["value"] for k in d2h} == d2h
+    # bytes sit on the transfer spans alone
+    for ev in events:
+        part = ev["name"].rpartition(".")[2]
+        assert ("h2d_bytes" in ev["args"]) == (part == "h2d")
+        assert ("d2h_bytes" in ev["args"]) == (part == "d2h")
+
+
+@pytest.mark.parametrize("stages", [None, ("distances", "comparison")])
+def test_tracing_changes_no_result(stages):
+    g = T.make("slimfly", q=5)
+    plain = AnalysisEngine(g, mesh=None, seed=5).report(stages)
+    obs.enable()
+    try:
+        seen = AnalysisEngine(g, mesh=None, seed=5).report(stages)
+    finally:
+        obs.disable()
+        obs.reset()
+        obs.meters.reset()
+    assert seen == plain

@@ -288,7 +288,8 @@ def test_squaring_telemetry_reports_convergence_step():
 def test_wavefront_host_wrapper_spans_under_tracing():
     """Under an enabled tracer the host wrapper spans the call, folds the
     device telemetry into the span attrs, and accounts the adjacency
-    upload bytes — while returning exactly the usual (dist, mult)."""
+    upload bytes on its upload span — while returning exactly the usual
+    (dist, mult)."""
     from repro import obs
 
     g = T.make("slimfly", q=5)
@@ -312,5 +313,6 @@ def test_wavefront_host_wrapper_spans_under_tracing():
     diam = int(want_d[np.isfinite(want_d)].max())
     assert span["args"]["converged_level"] == diam
     assert span["args"]["levels"] == diam + 1
-    assert span["args"]["h2d_bytes"] > 0
-    assert h2d.get("value", 0) == span["args"]["h2d_bytes"]
+    (upload,) = [ev for ev in events if ev["name"] == "wavefront.h2d"]
+    assert upload["args"]["h2d_bytes"] > 0
+    assert h2d.get("value", 0) == upload["args"]["h2d_bytes"]
