@@ -20,7 +20,7 @@ from ... import obs
 from ..graph import Graph
 from .apsp import apsp_dense, sampled_distances
 from .histograms import path_length_histogram
-from .paths import edge_interference, path_counts_with_slack
+from .paths import edge_interference, pair_rows, path_counts_with_slack
 
 __all__ = ["AnalysisEngine", "analyze", "path_diversity"]
 
@@ -258,17 +258,21 @@ class AnalysisEngine:
         paths = self.multiplicities()
         dist = self.distances()
         with obs.span("slack.host"):
-            off = np.isfinite(dist) & (dist > 0)
-            if not off.any():  # no reachable pair (edgeless / single router)
+            rows = paths.get("pair_rows")
+            if rows is None:  # the numpy path reduces on the host
+                rows = pair_rows(np, dist, paths["multiplicity"],
+                                 paths["plus1"], paths["plus2"])
+            # per-row sums (float32 on the device), finished in float64
+            count, s_mult, s_p1, s_p2, lo, hi = np.asarray(rows, np.float64)
+            pairs = count.sum()
+            if not pairs:  # no reachable pair (edgeless / single router)
                 return {}
-            mult, p1, p2 = (paths["multiplicity"], paths["plus1"],
-                            paths["plus2"])
             return {
-                "path_multiplicity_mean": float(mult[off].mean()),
-                "path_multiplicity_min": int(mult[off].min()),
-                "path_multiplicity_max": int(mult[off].max()),
-                "nonminimal_plus1_mean": float(p1[off].mean()),
-                "nonminimal_plus2_mean": float(p2[off].mean()),
+                "path_multiplicity_mean": float(s_mult.sum() / pairs),
+                "path_multiplicity_min": int(lo.min()),
+                "path_multiplicity_max": int(hi.max()),
+                "nonminimal_plus1_mean": float(s_p1.sum() / pairs),
+                "nonminimal_plus2_mean": float(s_p2.sum() / pairs),
                 "path_counts_exact": bool(paths["exact"]),
             }
 

@@ -21,7 +21,9 @@ reduces to semiring matmuls (`repro.kernels.semiring`):
 
       simple_paths(d+2) = A^(d+2) - T_d + d * multiplicity        (per pair)
 
-  evaluated with counting matmuls via T_L = A T_(L-1) + D A^L.
+  evaluated with counting matmuls via T_L = A T_(L-1) + D A^L. On the
+  kernel path the whole level loop runs as one device program
+  (`wavefront.slack_counts_device`).
 
 Counts on the kernel path are f32 and exact while every intermediate walk
 count stays below 2**24 (the numpy fallback accumulates in f64, exact to
@@ -40,7 +42,7 @@ from ..graph import Graph
 
 __all__ = [
     "shortest_path_multiplicity", "tropical_count_relaxation",
-    "path_counts_with_slack",
+    "path_counts_with_slack", "pair_rows",
     "pair_edge_loads", "edge_interference", "brute_force_path_counts",
 ]
 
@@ -224,54 +226,92 @@ def path_counts_with_slack(
     accumulator's exact-integer range (2**24 for the f32 kernel path, 2**53
     for the f64 numpy path): plus2 is a difference of large counts, so past
     that point it is clamped at zero but can still be off by the rounding.
+
+    The kernel path runs the whole level loop as one device program
+    (`wavefront.slack_counts_device`) and adds ``"pair_rows"``, the
+    report's per-row reductions (:func:`pair_rows`) taken on the device.
     """
-    from ..routing.assign import product_names
-
-    product = _count_product(use_kernel)
+    if use_kernel:
+        return _slack_counts_device(g, dist)
+    product = _count_product(False)
     n = g.n
-    with obs.span("slack.host"):
-        a = g.adjacency_dense(np.float32)
-        deg = g.degrees().astype(np.float32)
-        finite = np.isfinite(dist)
-        diam = int(dist[finite].max()) if finite.any() else 0
+    a = g.adjacency_dense(np.float32)
+    deg = g.degrees().astype(np.float32)
+    finite = np.isfinite(dist)
+    diam = int(dist[finite].max()) if finite.any() else 0
 
-        walks = np.eye(n, dtype=np.float32)       # A^L
-        bounce = np.diag(deg).astype(np.float32)  # T_L = sum_l A^l D A^(L-l)
-        mult = np.where(dist == 0, np.float32(1), np.float32(0))
-        plus1 = np.zeros((n, n), np.float32)
-        plus2 = np.zeros((n, n), np.float32)
-        correction = np.where(dist == 0, bounce, np.float32(0))  # T_d at d=0
+    walks = np.eye(n, dtype=np.float32)       # A^L
+    bounce = np.diag(deg).astype(np.float32)  # T_L = sum_l A^l D A^(L-l)
+    mult = np.where(dist == 0, np.float32(1), np.float32(0))
+    plus1 = np.zeros((n, n), np.float32)
+    plus2 = np.zeros((n, n), np.float32)
+    correction = np.where(dist == 0, bounce, np.float32(0))  # T_d at d=0
 
-    exact_limit = float(2 ** 24 if use_kernel else 2 ** 53)
+    exact_limit = float(2 ** 53)
     exact = True
     for level in range(1, diam + 3):
-        # each product uploads both operands, the adjacency included
-        with product_names("slack", "slack_walks", "slack_adjacency",
-                           "slack_walks"):
-            walks = product(walks, a)
-        with product_names("slack", "slack_bounce", "slack_adjacency",
-                           "slack_bounce"):
-            bounce_a = product(bounce, a)
-        with obs.span("slack.host"):
-            # T_L = T_(L-1) A + A^L D; the second term is a column scale
-            bounce = bounce_a + walks * deg[None, :]
-            exact = (exact and walks.max() <= exact_limit
-                     and bounce.max() <= exact_limit)
-            mult = np.where(dist == level, walks, mult)
-            plus1 = np.where(dist == level - 1, walks, plus1)
-            plus2 = np.where(dist == level - 2, walks, plus2)
-            correction = np.where(dist == level, bounce, correction)
+        walks = product(walks, a)
+        bounce_a = product(bounce, a)
+        # T_L = T_(L-1) A + A^L D; the second term is a column scale
+        bounce = bounce_a + walks * deg[None, :]
+        exact = (exact and walks.max() <= exact_limit
+                 and bounce.max() <= exact_limit)
+        mult = np.where(dist == level, walks, mult)
+        plus1 = np.where(dist == level - 1, walks, plus1)
+        plus2 = np.where(dist == level - 2, walks, plus2)
+        correction = np.where(dist == level, bounce, correction)
 
-    with obs.span("slack.host"):
-        d0 = np.where(finite, dist, 0.0).astype(np.float32)
-        # difference of large counts: clamp the rounding's negative excursions
-        plus2 = np.maximum(plus2 - correction + d0 * mult, 0.0)
-        # unreachable pairs carry no paths at any slack
-        mult = np.where(finite, mult, 0.0)
-        plus1 = np.where(finite, plus1, 0.0)
-        plus2 = np.where(finite & (dist > 0), plus2, 0.0)
+    d0 = np.where(finite, dist, 0.0).astype(np.float32)
+    # difference of large counts: clamp the rounding's negative excursions
+    plus2 = np.maximum(plus2 - correction + d0 * mult, 0.0)
+    # unreachable pairs carry no paths at any slack
+    mult = np.where(finite, mult, 0.0)
+    plus1 = np.where(finite, plus1, 0.0)
+    plus2 = np.where(finite & (dist > 0), plus2, 0.0)
     return {"multiplicity": mult, "plus1": plus1, "plus2": plus2,
             "exact": exact}
+
+
+def _slack_counts_device(g: Graph, dist: np.ndarray) -> Dict[str, np.ndarray]:
+    """The kernel path of :func:`path_counts_with_slack`: two uploads, one
+    device program for levels 1 .. diameter + 2, and the downloads."""
+    from ... import transfers
+    from .wavefront import pad_block, pad_operand, slack_counts_device
+
+    n = g.n
+    with obs.span("slack.host"):
+        finite = np.isfinite(dist)
+        diam = int(np.max(dist, where=finite, initial=0))
+        p, _ = pad_block(n)
+        adj = pad_operand(g.adjacency_dense(np.float32), p, 0)
+        dist_p = pad_operand(dist, p, np.inf)
+    out = slack_counts_device(transfers.upload(adj, "slack", "slack_adjacency"),
+                              transfers.upload(dist_p, "slack", "slack_dist"),
+                              n, diam)
+    levels = diam + 2
+    mult, plus1, plus2, rows, exact = transfers.wait(
+        out, "slack", levels=levels, products=2 * levels)
+    return {"multiplicity": transfers.download(mult, "slack", "slack_mult"),
+            "plus1": transfers.download(plus1, "slack", "slack_plus1"),
+            "plus2": transfers.download(plus2, "slack", "slack_plus2"),
+            "exact": bool(transfers.download(exact, "slack", "slack_exact")),
+            "pair_rows": transfers.download(rows, "slack", "slack_rows")}
+
+
+def pair_rows(xp, dist, mult, plus1, plus2):
+    """Per-row reductions over the reachable off-diagonal pairs, the
+    report's multiplicity summary in (6, n) rows: pair count, sums of
+    ``mult``, ``plus1`` and ``plus2``, min and max of ``mult`` (+inf and
+    -inf in a row without such pairs). ``xp`` is numpy or jax.numpy: the
+    device program and the host take the same reductions."""
+    off = xp.isfinite(dist) & (dist > 0)
+    return xp.stack([
+        off.sum(axis=1).astype(mult.dtype),
+        xp.where(off, mult, 0).sum(axis=1),
+        xp.where(off, plus1, 0).sum(axis=1),
+        xp.where(off, plus2, 0).sum(axis=1),
+        xp.where(off, mult, xp.inf).min(axis=1),
+        xp.where(off, mult, -xp.inf).max(axis=1)])
 
 
 def edge_interference(

@@ -11,11 +11,14 @@ Here the **entire level loop runs inside one jitted `jax.lax.while_loop`**:
 frontier expansion (the fused `frontier_step` Pallas primitive — counting
 matmul with the first-reach mask folded into its epilogue), dist/mult
 updates, and the convergence test all stay on device; only the final
-matrices are transferred to host. The same holds for the two other level
-loops in the stack:
+matrices are transferred to host. The same holds for the three other
+level loops in the stack:
 
 * :func:`ecmp_loads_device` — the O(diameter) Brandes dependency
   accumulation behind the exact ECMP saturation-throughput bound;
+* :func:`slack_counts_device` — the +1/+2 slack-count recurrence (walks
+  and bounce walks, levels 1 .. diameter + 2) with its masks, clamp and
+  the report's per-row reductions;
 * :func:`squaring_apsp_device` — weighted min-plus squaring with the
   convergence flag computed on device (the throughput engine's per-round
   oracle, fed by an on-device scatter of edge lengths into a reused padded
@@ -51,8 +54,8 @@ from ... import obs, transfers
 from ...device import resolve_interpret
 
 __all__ = ["wavefront_dist_mult", "dist_mult_device", "ecmp_loads_device",
-           "squaring_apsp_device", "pad_block", "pad_operand",
-           "telemetry_attrs"]
+           "slack_counts_device", "squaring_apsp_device", "pad_block",
+           "pad_operand", "telemetry_attrs"]
 
 _INF = np.float32(np.inf)
 
@@ -429,6 +432,80 @@ def ecmp_loads_device(dist: jnp.ndarray, mult: jnp.ndarray, adj: jnp.ndarray,
         return _ecmp_fn(dist.ndim == 3, block, interpret)(dist, mult, adj)
     return _ecmp_fn(dist.ndim == 3, block, interpret,
                     weighted=True)(dist, mult, adj, demand)
+
+
+@functools.lru_cache(maxsize=None)
+def _slack_fn(n: int, diameter: int, block: int, interpret: bool):
+    from ...kernels.semiring import COUNTING, semiring_matmul_pallas
+    from .paths import pair_rows
+
+    def count(a, b):
+        (out,) = semiring_matmul_pallas(COUNTING, (a,), (b,), bm=block,
+                                        bn=block, bk=block,
+                                        interpret=interpret)
+        return out
+
+    def run(adj, dist):
+        deg = jnp.sum(adj, axis=0)
+        walks = jnp.eye(adj.shape[-1], dtype=jnp.float32)   # A^L
+        bounce = walks * deg[None, :]  # T_L = sum_l A^l D A^(L-l)
+        zeros = jnp.zeros_like(adj)
+        mult = jnp.where(dist == 0, 1.0, zeros)
+        correction = jnp.where(dist == 0, bounce, zeros)    # T_d at d=0
+
+        def level(lv, state):
+            walks, bounce, mult, plus1, plus2, correction, peak = state
+            lf = lv.astype(jnp.float32)
+            walks = count(walks, adj)
+            # T_L = T_(L-1) A + A^L D; the second term is a column scale
+            bounce = count(bounce, adj) + walks * deg[None, :]
+            peak = jnp.maximum(peak, jnp.maximum(walks.max(), bounce.max()))
+            mult = jnp.where(dist == lf, walks, mult)
+            plus1 = jnp.where(dist == lf - 1, walks, plus1)
+            plus2 = jnp.where(dist == lf - 2, walks, plus2)
+            correction = jnp.where(dist == lf, bounce, correction)
+            return walks, bounce, mult, plus1, plus2, correction, peak
+
+        _, _, mult, plus1, plus2, correction, peak = jax.lax.fori_loop(
+            1, diameter + 3, level,
+            (walks, bounce, mult, zeros, zeros, correction,
+             jnp.float32(0)))
+        finite = jnp.isfinite(dist)
+        d0 = jnp.where(finite, dist, 0.0)
+        # difference of large counts: clamp the rounding's negative excursions
+        plus2 = jnp.maximum(plus2 - correction + d0 * mult, 0.0)
+        # unreachable pairs carry no paths at any slack
+        mult = jnp.where(finite, mult, 0.0)[:n, :n]
+        plus1 = jnp.where(finite, plus1, 0.0)[:n, :n]
+        plus2 = jnp.where(finite & (dist > 0), plus2, 0.0)[:n, :n]
+        rows = pair_rows(jnp, dist[:n, :n], mult, plus1, plus2)
+        return mult, plus1, plus2, rows, peak <= 2.0 ** 24
+
+    return jax.jit(run)
+
+
+def slack_counts_device(adj: jnp.ndarray, dist: jnp.ndarray, n: int,
+                        diameter: int, interpret: Optional[bool] = None):
+    """Simple-path counts at slack 0 / +1 / +2, fully on device.
+
+    The recurrence of `paths.path_counts_with_slack` for levels 1 ..
+    ``diameter`` + 2 as one jitted `lax.fori_loop`: two counting products
+    per level (walks and bounces, the same float32 Pallas kernel at
+    HIGHEST precision), the level masks, the final clamp and the report's
+    per-row reductions (`paths.pair_rows`) all on the device. ``adj`` and
+    ``dist`` are (p, p) padded operands (adjacency 0, distance +inf in the
+    padding). Returns device arrays ``(mult, plus1, plus2, rows, exact)``:
+    the (n, n) counts, the (6, n) reductions and whether every walk count
+    stayed within float32's exact-integer range (2**24). One compiled
+    program per (n, diameter).
+    """
+    from ...kernels import autotune
+
+    interpret = resolve_interpret(interpret)
+    p = adj.shape[-1]
+    cfg = autotune.resolve("count", p, p, p)
+    block = cfg["bm"] if p % cfg["bm"] == 0 else 128
+    return _slack_fn(n, diameter, block, interpret)(adj, dist)
 
 
 @functools.lru_cache(maxsize=None)

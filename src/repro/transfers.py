@@ -39,12 +39,12 @@ def upload(x, stage: str, what: str, dtype=None) -> jax.Array:
     return out
 
 
-def wait(x, stage: str):
+def wait(x, stage: str, **attrs):
     """While tracing, block on ``x`` (any pytree of device arrays) under a
-    ``<stage>.wait`` span: the time the host spends waiting for the
-    device to finish the work it was given."""
+    ``<stage>.wait`` span carrying ``attrs``: the time the host spends
+    waiting for the device to finish the work it was given."""
     if obs.enabled():
-        with obs.span(f"{stage}.wait"):
+        with obs.span(f"{stage}.wait", **attrs):
             jax.block_until_ready(x)
     return x
 

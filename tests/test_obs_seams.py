@@ -4,9 +4,11 @@ transfer counts its bytes, and tracing changes no result.
 
 On Slim Fly q=5 (n = 50 routers, padded to p = 128 for the device loops)
 the bytes follow from the operand shapes alone: the wavefront uploads the
-padded float32 adjacency and downloads padded dist and mult; each of the
-8 slack-count products (walks and bounces, levels 1 .. diameter + 2)
-uploads two n x n float32 operands and downloads one; the histogram
+padded float32 adjacency and downloads padded dist and mult; the slack
+counts upload the padded adjacency and dist once, run levels 1 ..
+diameter + 2 as one device program, and download mult, plus1 and plus2 at
+n x n, the report's (6, n) row reductions and the one-byte exact flag; the
+histogram
 uploads dist and downloads 65 int32 bins; spectral uploads two
 Laplacians; ECMP uploads padded dist, mult and adjacency and downloads the
 padded loads.
@@ -39,10 +41,10 @@ def _bytes(stages, n, p):
     h2d = {"adjacency": pp}
     d2h = {"wavefront_dist": pp, "wavefront_mult": pp}
     if stages is None:
-        h2d.update(slack_walks=4 * nn, slack_bounce=4 * nn,
-                   slack_adjacency=8 * nn, histogram_dist=nn,
+        h2d.update(slack_adjacency=pp, slack_dist=pp, histogram_dist=nn,
                    laplacian=2 * nn)
-        d2h.update(slack_walks=4 * nn, slack_bounce=4 * nn,
+        d2h.update(slack_mult=nn, slack_plus1=nn, slack_plus2=nn,
+                   slack_rows=6 * n * F32, slack_exact=1,
                    histogram_counts=65 * 4)
     else:
         h2d.update(ecmp_dist=pp, ecmp_mult=pp, ecmp_adjacency=pp)
@@ -83,6 +85,12 @@ def test_report_splits_into_leaf_spans_with_exact_bytes(stages, traced):
     snap = obs.snapshot()
     assert {k: snap[f"h2d_bytes.{k}"]["value"] for k in h2d} == h2d
     assert {k: snap[f"d2h_bytes.{k}"]["value"] for k in d2h} == d2h
+    if stages is None:
+        # the slack counts' whole level loop is one device program
+        diameter = 2
+        (wait,) = [ev for ev in events if ev["name"] == "slack.wait"]
+        assert wait["args"]["levels"] == diameter + 2
+        assert wait["args"]["products"] == 2 * (diameter + 2)
     # bytes sit on the transfer spans alone
     for ev in events:
         part = ev["name"].rpartition(".")[2]

@@ -20,7 +20,24 @@ def _complete(n):
         [(i, j) for i in range(n) for j in range(i + 1, n)]), name=f"K{n}")
 
 
-KNOWN = [_ring(8), _ring(5), _complete(5), T.make("torus", dims=(2, 3))]
+def _two_paths():
+    # disconnected: +inf distances between the halves
+    return Graph(n=6, edges=np.array([(0, 1), (1, 2), (3, 4), (4, 5)]),
+                 name="two-paths")
+
+
+def _rings(copies, n):
+    # disjoint n-rings: copies * n routers, +inf between the rings
+    edges = [(c * n + i, c * n + (i + 1) % n)
+             for c in range(copies) for i in range(n)]
+    return Graph(n=copies * n, edges=np.array(edges),
+                 name=f"{copies}xring{n}")
+
+
+# 26xring5 has 130 routers and pads to 256: the device loops run over a
+# second, mostly phantom tile
+KNOWN = [_ring(8), _ring(5), _complete(5), T.make("torus", dims=(2, 3)),
+         _two_paths(), _rings(26, 5)]
 
 
 @pytest.mark.parametrize("g", KNOWN, ids=lambda g: g.name)
@@ -40,9 +57,23 @@ def test_slack_counts_match_brute_force(g):
     bf = brute_force_path_counts(g)
     dist = apsp_dense(g, use_kernel=False)
     pc = path_counts_with_slack(g, dist, use_kernel=True)
-    np.testing.assert_array_equal(pc["multiplicity"], bf["multiplicity"])
-    np.testing.assert_array_equal(pc["plus1"], bf["plus1"])
-    np.testing.assert_array_equal(pc["plus2"], bf["plus2"])
+    oracle = path_counts_with_slack(g, dist, use_kernel=False)
+    for key in ("multiplicity", "plus1", "plus2"):
+        np.testing.assert_array_equal(pc[key], bf[key], err_msg=key)
+        np.testing.assert_array_equal(pc[key], oracle[key], err_msg=key)
+    assert pc["exact"] and oracle["exact"]
+
+
+def test_slack_counts_flag_walks_past_f32():
+    # K200: the +2 bounce walks reach ~4 * 199^3 > 2**24, so the float32
+    # device path says its counts are not exact; the f64 oracle's are
+    g = _complete(200)
+    dist = apsp_dense(g, use_kernel=False)
+    pc = path_counts_with_slack(g, dist, use_kernel=True)
+    oracle = path_counts_with_slack(g, dist, use_kernel=False)
+    assert not pc["exact"] and oracle["exact"]
+    np.testing.assert_array_equal(pc["multiplicity"], oracle["multiplicity"])
+    np.testing.assert_array_equal(pc["plus1"], oracle["plus1"])
 
 
 def test_known_ring_counts():
@@ -81,8 +112,7 @@ def test_slimfly_multiplicity_exact():
 
 
 def test_disconnected_pairs_count_zero():
-    g = Graph(n=6, edges=np.array([(0, 1), (1, 2), (3, 4), (4, 5)]),
-              name="two-paths")
+    g = _two_paths()
     dist = apsp_dense(g, use_kernel=False)
     d, m = shortest_path_multiplicity(g, use_kernel=False)
     assert not np.isfinite(d[0, 3]) and m[0, 3] == 0

@@ -83,6 +83,16 @@ def test_weighted_ecmp_loop(one_chip, batched):
              *[(shape, jnp.float32)] * 4, sharding=one_chip)
 
 
+def test_slack_counts_program_slimfly(one_chip):
+    # the report's +1/+2 level loop at diameter 2, as slack_counts_device
+    # resolves its block
+    p, _ = WF.pad_block(3362)
+    block = autotune.resolve("count", p, p, p)["bm"]
+    assert p % block == 0
+    _compile(WF._slack_fn(3362, 2, block, False),
+             ((p, p), jnp.float32), ((p, p), jnp.float32), sharding=one_chip)
+
+
 @pytest.mark.parametrize("p", [512, 1024])
 def test_minplus_squaring_at_tuned_block(one_chip, p):
     # the throughput oracle's squaring loop at the block squaring_apsp_device
