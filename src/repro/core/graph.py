@@ -67,6 +67,23 @@ class Graph:
             return int(self.meta["num_servers"])
         return self.n * self.concentration
 
+    def server_counts(self) -> np.ndarray:
+        """(n,) int64 servers attached to each router: ``concentration`` on
+        every router, or ``meta["edge_concentration"]`` on the last
+        ``meta["n_edge"]`` routers alone (the fat tree's edge switches).
+
+        Raises ValueError for a graph whose generator records
+        ``num_servers`` without saying where they attach.
+        """
+        counts = np.full(self.n, self.concentration, np.int64)
+        if "edge_concentration" in self.meta:
+            counts[self.n - int(self.meta["n_edge"]):] = int(
+                self.meta["edge_concentration"])
+        if int(counts.sum()) != self.num_servers:
+            raise ValueError(f"{self.name}: where its {self.num_servers} "
+                             f"servers attach is not recorded")
+        return counts
+
     def degrees(self) -> np.ndarray:
         d = np.zeros(self.n, dtype=np.int64)
         np.add.at(d, self.edges[:, 0], 1)

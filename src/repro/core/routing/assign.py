@@ -394,10 +394,11 @@ def ecmp_demand_loads(dist: np.ndarray, mult: np.ndarray, adj: np.ndarray,
             return _ecmp_demand_host_shared(
                 dist, mult, adj,
                 np.ascontiguousarray(np.broadcast_to(demand, shape)))
-        dist = np.ascontiguousarray(np.broadcast_to(dist, shape))
-        mult = np.ascontiguousarray(np.broadcast_to(mult, shape))
-        adj = np.ascontiguousarray(np.broadcast_to(adj, shape))
-        demand = np.ascontiguousarray(np.broadcast_to(demand, shape))
+        with obs.span("traffic.host"):
+            dist = np.ascontiguousarray(np.broadcast_to(dist, shape))
+            mult = np.ascontiguousarray(np.broadcast_to(mult, shape))
+            adj = np.ascontiguousarray(np.broadcast_to(adj, shape))
+            demand = np.ascontiguousarray(np.broadcast_to(demand, shape))
     if product is None and use_kernel:
         return _ecmp_demand_device(dist, mult, adj, demand)
     if product is None:
@@ -464,21 +465,31 @@ def _ecmp_demand_host_shared(dist: np.ndarray, mult: np.ndarray,
 
 def _ecmp_demand_device(dist: np.ndarray, mult: np.ndarray, adj: np.ndarray,
                         demand: np.ndarray) -> np.ndarray:
-    """Pad all four operands -> weighted device Brandes -> sliced loads."""
-    import jax.numpy as jnp
-
+    """Pad all four operands -> weighted device Brandes -> sliced loads,
+    each seam a ``traffic.*`` span (`repro.transfers`)."""
+    from ... import transfers
     from ..analysis.wavefront import ecmp_loads_device, pad_block, pad_operand
 
     n = dist.shape[-1]
     batched = dist.ndim == 3
     p, block = pad_block(n, batched=batched)
-    loads = ecmp_loads_device(jnp.asarray(pad_operand(dist, p, np.inf)),
-                              jnp.asarray(pad_operand(mult, p, 0.0)),
-                              jnp.asarray(pad_operand(adj, p, 0.0)),
-                              demand=jnp.asarray(pad_operand(demand, p, 0.0)),
+
+    def operand(x, fill, what):
+        # pad and upload one operand at a time: one padded host copy lives
+        with obs.span("traffic.host"):
+            x = pad_operand(x, p, fill)
+        return transfers.upload(x, "traffic", what)
+
+    loads = ecmp_loads_device(operand(dist, np.inf, "traffic_dist"),
+                              operand(mult, 0.0, "traffic_mult"),
+                              operand(adj, 0.0, "traffic_adjacency"),
+                              demand=operand(demand, 0.0, "traffic_demand"),
                               block=block)
-    sl = (Ellipsis, slice(None, n), slice(None, n))
-    return np.asarray(loads)[sl].astype(np.float64)
+    loads = transfers.download(transfers.wait(loads, "traffic"), "traffic",
+                               "traffic_loads")
+    with obs.span("traffic.host"):
+        sl = (Ellipsis, slice(None, n), slice(None, n))
+        return loads[sl].astype(np.float64)
 
 
 def walk_slack_link_loads(g: Graph, dist: np.ndarray, demand: np.ndarray,

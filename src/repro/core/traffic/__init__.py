@@ -4,7 +4,8 @@
 specification every engine speaks (pattern registry, flag grammar,
 spec -> pairs / matrix / stacked-batch) and the single home of the
 unreachable-demand contract. `traffic.patterns` registers the scenario
-suite (uniform, permutation, tornado, shift, bitcomp, hotspot, bursty).
+suite (uniform, permutation, tornado, shift, bitcomp, hotspot, bursty,
+and the server-level server_permutation).
 `traffic.scenarios` evaluates whole demand batches as one stacked pass
 and bisects saturation rates; `traffic.grid` crosses scenarios with
 `core.resilience` failure severities into the traffic x failure grid

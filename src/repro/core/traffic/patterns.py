@@ -1,12 +1,18 @@
 """The registered demand-pattern suite: seeded stacked (S, n, n) generators.
 
-Every generator obeys one contract, pinned by the invariant tests:
+Every router generator obeys one contract, pinned by the invariant tests:
 
 * output is ``(samples, n, n)`` float64 with a zero diagonal;
 * every *live* row sums to exactly ``rate`` (the per-router injection
   rate); ``bursty`` rows are ``rate`` in an on-phase and 0 in an
   off-phase, so its time-average injection is ``duty * rate``;
 * generators draw only from the passed ``rng`` — same seed, same batch.
+
+The server pattern (``server_permutation``) sends between the servers
+behind the routers: ``rate`` is per server, row i and column i each sum
+to ``rate * servers[i]``, and flows between two servers of one router land
+on the diagonal, which the engines drop and count in
+``dropped_demand_frac`` (contract: `traffic.spec`).
 
 The suite is the SpiNNaker network_tester scenario set (synchronized
 bursts, hot-spot discovery) plus the classic adversarial k-ary-n-cube
@@ -37,6 +43,11 @@ evaluates:
                   (the network_tester synchronized burst), ``sync=0``
                   gates each router independently; on-rows inject
                   ``uniform`` at ``rate``.
+``server_permutation``  every server sends ``rate`` to exactly one other
+                  server and receives from exactly one (a random
+                  derangement of all servers per sample): the random
+                  permutation traffic of the Jellyfish throughput studies
+                  (Singla et al., NSDI 2012), summed into router demand.
 """
 from __future__ import annotations
 
@@ -163,3 +174,31 @@ def bursty(n: int, rate: float, rng: np.random.Generator, samples: int,
     else:
         on = rng.random((samples, n)) < duty
     return np.where(on[:, :, None], base[None], 0.0)
+
+
+@register("server_permutation", servers=True)
+def server_permutation(n: int, rate: float, rng: np.random.Generator,
+                       samples: int, servers: np.ndarray) -> np.ndarray:
+    """Random server permutations, summed into ``(samples, n, n)`` router
+    demand: entry (i, j) is ``rate`` times the number of servers of router
+    i whose destination is a server of router j.
+
+    Servers are numbered router by router (router 0's ``servers[0]``
+    first). Draw order, per sample: ``rng.permutation(N)`` over the N
+    servers, drawn again while any server maps to itself; server k sends
+    to server ``perm[k]``. Nothing else is drawn.
+    """
+    router = np.repeat(np.arange(n), servers)
+    total = len(router)
+    if total < 2:
+        raise ValueError(f"server_permutation needs at least two servers, "
+                         f"the graph has {total}")
+    ident = np.arange(total)
+    out = np.empty((samples, n, n), np.float64)
+    for s in range(samples):
+        perm = rng.permutation(total)
+        while (perm == ident).any():
+            perm = rng.permutation(total)
+        flows = np.bincount(router * n + router[perm], minlength=n * n)
+        np.multiply(flows.reshape(n, n), rate, out=out[s])
+    return out

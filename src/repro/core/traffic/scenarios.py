@@ -63,7 +63,8 @@ def demand_batch(g: Graph, demand: DemandLike,
     """
     if isinstance(demand, (str, TrafficSpec)):
         spec = as_spec(demand)
-        return spec.batch(g, samples=samples), spec.describe()
+        with obs.span("demand.host"):
+            return spec.batch(g, samples=samples), spec.describe()
     d = np.asarray(demand, np.float64)
     if d.ndim == 2:
         d = d[None]
@@ -83,7 +84,8 @@ def _dist_mult(adj: np.ndarray, use_kernel: bool
         from ..analysis.wavefront import wavefront_dist_mult
 
         dist, mult = wavefront_dist_mult(adj)
-        return dist, mult.astype(np.float64)
+        with obs.span("traffic.host"):
+            return dist, mult.astype(np.float64)
     from ..sweep import _batched_count, batched_dist_mult
 
     batched = adj.ndim == 3
@@ -99,12 +101,8 @@ def _traffic_metrics(loads: np.ndarray, dist: np.ndarray,
     from ..resilience.degradation import _masked_mean, _masked_percentiles
 
     s, n, _ = loads.shape
-    idx = np.arange(n)
-    offered = np.array(demand, np.float64, copy=True)
-    if offered.ndim == 2:
-        offered = np.broadcast_to(offered, loads.shape).copy()
-    offered[:, idx, idx] = 0.0                     # self-demand never routes
-    off = np.isfinite(dist) & (dist > 0)
+    offered = np.broadcast_to(np.asarray(demand, np.float64), loads.shape)
+    off = np.isfinite(dist) & (dist > 0)           # self-demand never routes
     routed = np.where(off, offered, 0.0)
     total = offered.reshape(s, -1).sum(1)
     routed_sum = routed.reshape(s, -1).sum(1)
@@ -145,13 +143,18 @@ def evaluate_traffic_batch(g: Graph, demand: DemandLike,
     from the resilience working-set budget when None); the routing state
     (``dist``/``mult``) is computed once — pass precomputed ``(n, n)``
     arrays (e.g. a sweep's slices) to skip even that.
+
+    Traced, demand generation is the ``demand.host`` span and the passes
+    run inside ``traffic.scenario``, whose ``products`` attribute counts
+    the weighted counting products (2 per BFS level per matrix).
     """
     from ..resilience.degradation import _auto_chunk
     from ..routing.assign import ecmp_demand_loads
 
     batch, label = demand_batch(g, demand)
     s, n = len(batch), g.n
-    adj = g.adjacency_dense()
+    with obs.span("traffic.host"):
+        adj = g.adjacency_dense()
     if dist is None or mult is None:
         dist, mult = _dist_mult(adj, use_kernel)
     if mask_chunk is None:
@@ -163,12 +166,19 @@ def evaluate_traffic_batch(g: Graph, demand: DemandLike,
             d = batch[lo:lo + mask_chunk]
             loads = ecmp_demand_loads(dist, mult, adj, d,
                                       use_kernel=use_kernel)
-            parts.append(_traffic_metrics(loads, dist[None], d,
-                                          2 * len(g.edges), capacity))
-        out = {k: np.concatenate([p[k] for p in parts]) for k in parts[0]}
-        sp.set(passes=len(parts),
-               max_link_load=float(out["max_link_load"].max()),
-               dropped=float(out["dropped_demand_frac"].mean()))
+            with obs.span("traffic.host"):
+                parts.append(_traffic_metrics(loads, dist[None], d,
+                                              2 * len(g.edges), capacity))
+        with obs.span("traffic.host"):
+            out = {k: np.concatenate([p[k] for p in parts])
+                   for k in parts[0]}
+            if sp:
+                finite = np.isfinite(dist)
+                diameter = int(dist[finite].max()) if finite.any() else 0
+                sp.set(passes=len(parts), diameter=diameter,
+                       products=2 * diameter * s,
+                       max_link_load=float(out["max_link_load"].max()),
+                       dropped=float(out["dropped_demand_frac"].mean()))
     return out
 
 

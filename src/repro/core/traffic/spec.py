@@ -17,8 +17,11 @@ spec -> pairs / matrix / stacked-batch path:
 
 Patterns are registered like topology families (`topology.base`): a
 generator ``fn(n, rate, rng, samples, **params) -> (S, n, n) float64``
-under a name; see `traffic.patterns` for the shipped suite. Specs parse
-from and print to the shared CLI flag grammar::
+under a name; see `traffic.patterns` for the shipped suite. A pattern
+registered with ``servers=True`` sends between servers, not routers: its
+generator also takes ``servers``, the (n,) per-router server counts, which
+:meth:`TrafficSpec.batch` reads from the graph (`Graph.server_counts`).
+Specs parse from and print to the shared CLI flag grammar::
 
     permutation
     hotspot:zipf_a=1.4,samples=8
@@ -57,6 +60,8 @@ __all__ = ["TrafficSpec", "as_spec", "register", "patterns", "generate",
 PatternFn = Callable[..., np.ndarray]
 
 _REGISTRY: Dict[str, PatternFn] = {}
+#: the registered patterns whose generators take ``servers``
+_SERVER_PATTERNS: set = set()
 
 #: spec fields the flag grammar binds directly (everything else is a
 #: generator parameter)
@@ -64,11 +69,14 @@ _INT_FIELDS = ("seed", "samples", "flows")
 _FLOAT_FIELDS = ("rate", "volume")
 
 
-def register(name: str):
-    """Register a demand-pattern generator under ``name`` (decorator)."""
+def register(name: str, servers: bool = False):
+    """Register a demand-pattern generator under ``name`` (decorator);
+    ``servers=True`` hands it the per-router server counts."""
 
     def deco(fn: PatternFn) -> PatternFn:
         _REGISTRY[name] = fn
+        if servers:
+            _SERVER_PATTERNS.add(name)
         return fn
 
     return deco
@@ -87,14 +95,30 @@ def _pattern(name: str) -> PatternFn:
 
 
 def generate(name: str, n: int, rate: float = 1.0, seed: int = 0,
-             samples: int = 1, **params) -> np.ndarray:
-    """Run the registered generator: ``(samples, n, n)`` float64 demand."""
+             samples: int = 1, servers: Optional[np.ndarray] = None,
+             **params) -> np.ndarray:
+    """Run the registered generator: ``(samples, n, n)`` float64 demand.
+
+    The generator draws from ``numpy.random.default_rng([seed, tag])`` and
+    nothing else, ``tag`` the first 8 bytes of the pattern's name read as a
+    big-endian integer (zero-padded), mod 2^31. A server pattern needs
+    ``servers``, the (n,) per-router server counts.
+    """
     if n < 1:
         raise ValueError("traffic needs at least one router")
     if samples < 1:
         raise ValueError("samples must be >= 1")
+    fn = _pattern(name)
+    if name in _SERVER_PATTERNS:
+        if servers is None:
+            raise ValueError(f"pattern {name!r} sends between servers: "
+                             f"give it a graph, not a router count")
+        params["servers"] = np.asarray(servers, np.int64)
+        if params["servers"].shape != (n,):
+            raise ValueError(f"servers has shape {params['servers'].shape},"
+                             f" wanted ({n},)")
     rng = np.random.default_rng([int(seed), _stable_tag(name)])
-    out = _pattern(name)(int(n), float(rate), rng, int(samples), **params)
+    out = fn(int(n), float(rate), rng, int(samples), **params)
     out = np.asarray(out, np.float64)
     if out.shape != (samples, n, n):
         raise RuntimeError(f"pattern {name!r} returned {out.shape}, "
@@ -151,11 +175,13 @@ def sample_pairs_from_matrix(matrix: np.ndarray, flows: int,
 class TrafficSpec:
     """One demand scenario: pattern + rate/seed/samples (+ flow sampling).
 
-    ``rate`` is the per-router injection rate: every registered pattern
-    emits matrices whose live row sums equal ``rate`` (bursty rows are
-    ``rate`` in an on-phase and 0 in an off-phase). ``samples`` is the
-    stacked-batch depth — independent draws for stochastic patterns, the
-    time axis for ``bursty``, identical copies for deterministic ones.
+    ``rate`` is the per-router injection rate: every registered router
+    pattern emits matrices whose live row sums equal ``rate`` (bursty rows
+    are ``rate`` in an on-phase and 0 in an off-phase). For a server
+    pattern it is the per-server rate, so row i sums to ``rate`` times the
+    servers of router i. ``samples`` is the stacked-batch depth —
+    independent draws for stochastic patterns, the time axis for
+    ``bursty``, identical copies for deterministic ones.
 
     With ``flows`` set the spec is in *flow-sampled* mode: ``pairs()``
     draws exactly that many flows from the pattern's demand distribution
@@ -184,9 +210,9 @@ class TrafficSpec:
         object.__setattr__(self, "params", p)
         for name, _ in p:
             if name in _INT_FIELDS or name in _FLOAT_FIELDS or \
-                    name == "pattern":
-                raise ValueError(f"{name!r} is a spec field, not a "
-                                 f"generator parameter")
+                    name in ("pattern", "servers"):
+                raise ValueError(f"{name!r} is a spec field or the graph's,"
+                                 f" not a generator parameter")
 
     # -- construction ------------------------------------------------------
     @classmethod
@@ -254,12 +280,20 @@ class TrafficSpec:
         return self.with_(rate=self.rate * float(factor))
 
     # -- materialization ---------------------------------------------------
+    def _generate(self, g, samples: int) -> np.ndarray:
+        graph = not isinstance(g, (int, np.integer))
+        servers = (g.server_counts()
+                   if graph and self.pattern in _SERVER_PATTERNS else None)
+        return generate(self.pattern, g.n if graph else int(g),
+                        rate=self.rate, seed=self.seed, samples=samples,
+                        servers=servers, **self.extras)
+
     def batch(self, g, samples: Optional[int] = None) -> np.ndarray:
-        """``(S, n, n)`` stacked demand matrices over graph/int ``g``."""
+        """``(S, n, n)`` stacked demand matrices over graph/int ``g`` (a
+        server pattern needs the graph)."""
         n = g if isinstance(g, (int, np.integer)) else g.n
         s = int(samples) if samples is not None else self.samples
-        base = generate(self.pattern, n, rate=self.rate, seed=self.seed,
-                        samples=s, **self.extras)
+        base = self._generate(g, s)
         if self.flows is None:
             return base
         rng = np.random.default_rng([int(self.seed), 0x70AD])
@@ -277,9 +311,7 @@ class TrafficSpec:
         """``(flows, 2)`` sampled flow pairs — flow-sampled mode only."""
         if self.flows is None:
             raise ValueError(f"{self.describe()}: pairs() needs flows=N")
-        n = g if isinstance(g, (int, np.integer)) else g.n
-        base = generate(self.pattern, n, rate=self.rate, seed=self.seed,
-                        samples=1, **self.extras)
+        base = self._generate(g, 1)
         rng = np.random.default_rng([int(self.seed), 0x70AD])
         return sample_pairs_from_matrix(base[0], self.flows, rng)
 

@@ -11,7 +11,8 @@ n x n, the report's (6, n) row reductions and the one-byte exact flag; the
 histogram
 uploads dist and downloads 65 int32 bins; spectral uploads two
 Laplacians; ECMP uploads padded dist, mult and adjacency and downloads the
-padded loads.
+padded loads. The traffic engine uploads, per pass of a stacked chunk,
+padded dist, mult, adjacency and demand and downloads the padded loads.
 """
 import numpy as np
 import pytest
@@ -110,3 +111,43 @@ def test_tracing_changes_no_result(stages):
         obs.reset()
         obs.meters.reset()
     assert seen == plain
+
+
+def test_traffic_stage_splits_into_leaf_spans_with_exact_bytes(traced):
+    from repro.core.traffic import evaluate_traffic_batch
+
+    g = T.make("slimfly", q=5)
+    samples, chunk, diameter = 3, 2, 2
+    evaluate_traffic_batch(g, "server_permutation:samples=3,seed=2",
+                           mask_chunk=chunk)
+    events = [ev for ev in obs.events() if ev["ph"] == "X"]
+    (scenario,) = [ev for ev in events if ev["name"] == "traffic.scenario"]
+    lo, hi = scenario["ts"], scenario["ts"] + scenario["dur"]
+    inside = [ev for ev in events if ev is not scenario
+              and lo <= ev["ts"] and ev["ts"] + ev["dur"] <= hi]
+    assert {ev["name"] for ev in inside} == {
+        "traffic.host", "traffic.h2d", "traffic.wait", "traffic.d2h"}
+    # every traffic transfer and wait nests under the scenario; demand
+    # generation runs before it
+    assert all(ev in inside for ev in events
+               if ev["name"] in ("traffic.h2d", "traffic.wait",
+                                 "traffic.d2h"))
+    (demand,) = [ev for ev in events if ev["name"] == "demand.host"]
+    assert demand["ts"] + demand["dur"] <= lo
+
+    # per pass: dist, mult, adjacency and demand up, the loads down, each
+    # a (chunk, p, p) float32 stack
+    p = pad_block(g.n, batched=True)[0]
+    h2d = sum(ev["args"].get("h2d_bytes", 0) for ev in inside)
+    d2h = sum(ev["args"].get("d2h_bytes", 0) for ev in inside)
+    assert h2d == 4 * samples * p * p * F32
+    assert d2h == samples * p * p * F32
+    snap = obs.snapshot()
+    assert snap["d2h_bytes.traffic_loads"]["value"] == d2h
+    assert [ev["args"]["what"] for ev in inside
+            if ev["name"] == "traffic.h2d"] == [
+        "traffic_dist", "traffic_mult", "traffic_adjacency",
+        "traffic_demand"] * 2
+    assert len([ev for ev in inside if ev["name"] == "traffic.wait"]) == 2
+    assert scenario["args"]["diameter"] == diameter
+    assert scenario["args"]["products"] == 2 * diameter * samples

@@ -36,7 +36,7 @@ ALL_PATTERNS = ("uniform", "permutation", "tornado", "shift", "bitcomp",
 
 
 def test_registry_covers_suite():
-    assert set(ALL_PATTERNS) <= set(pattern_names())
+    assert set(ALL_PATTERNS) | {"server_permutation"} <= set(pattern_names())
 
 
 @pytest.mark.parametrize("name", ALL_PATTERNS)
@@ -105,12 +105,85 @@ def test_shift_rejects_degenerate_k():
         generate("shift", 8, shift=8)
 
 
+# ------------------------------------------------------ server patterns
+
+SERVER_GRAPHS = {
+    "slimfly_q5": lambda: topo.make("slimfly", q=5),
+    "fattree_k4": lambda: topo.make("fattree", k=4),
+}
+
+
+def test_server_counts_per_family():
+    sf = SERVER_GRAPHS["slimfly_q5"]()
+    assert np.all(sf.server_counts() == sf.concentration)
+    ft = SERVER_GRAPHS["fattree_k4"]()
+    counts = ft.server_counts()
+    n_edge = ft.meta["n_edge"]
+    assert np.all(counts[:-n_edge] == 0)
+    assert np.all(counts[-n_edge:] == ft.meta["edge_concentration"])
+    assert counts.sum() == ft.num_servers
+    with pytest.raises(ValueError, match="not recorded"):
+        topo.make("megafly", m=2).server_counts()
+
+
+@pytest.mark.parametrize("graph", sorted(SERVER_GRAPHS))
+def test_server_permutation_row_and_column_sums(graph):
+    g = SERVER_GRAPHS[graph]()
+    rate = 2.5
+    out = TrafficSpec.parse(
+        f"server_permutation:rate={rate},samples=8,seed=3").batch(g)
+    assert out.shape == (8, g.n, g.n) and out.dtype == np.float64
+    want = rate * g.server_counts()
+    assert np.all(out.sum(axis=2) == want)
+    assert np.all(out.sum(axis=1) == want)
+    assert np.all(out >= 0.0)
+
+
+@pytest.mark.parametrize("graph", sorted(SERVER_GRAPHS))
+def test_server_permutation_seeded_and_round_trips(graph):
+    g = SERVER_GRAPHS[graph]()
+    spec = TrafficSpec.parse("server_permutation:samples=8,seed=5")
+    assert TrafficSpec.parse(spec.describe()) == spec
+    np.testing.assert_array_equal(spec.batch(g), spec.batch(g))
+    assert not np.array_equal(spec.batch(g), spec.with_(seed=6).batch(g))
+
+
+def test_server_permutation_sends_no_server_to_itself():
+    # one server per router: a self-send would sit on the diagonal, where
+    # an unconstrained permutation puts one per sample on average
+    g = topo.make("torus", dims=(12,), concentration=1)
+    out = TrafficSpec.parse("server_permutation:samples=64,seed=1").batch(g)
+    assert np.all(np.diagonal(out, axis1=1, axis2=2) == 0.0)
+    assert np.all(out.sum(axis=2) == 1.0) and np.all(out.sum(axis=1) == 1.0)
+
+
+def test_server_permutation_fat_tree_core_and_aggregation_silent():
+    g = SERVER_GRAPHS["fattree_k4"]()
+    out = TrafficSpec.parse("server_permutation:samples=8,seed=2").batch(g)
+    inner = g.n - g.meta["n_edge"]          # core and aggregation routers
+    assert not out[:, :inner, :].any() and not out[:, :, :inner].any()
+    res = evaluate_traffic_batch(g, out)
+    # every flow leaves an edge switch: 4 hops between pods, 2 within one
+    assert np.all((res["avg_hops"] >= 2.0) & (res["avg_hops"] <= 4.0))
+
+
+def test_server_permutation_needs_servers():
+    g = topo.make("torus", dims=(6,), concentration=0)
+    with pytest.raises(ValueError, match="at least two servers"):
+        TrafficSpec.parse("server_permutation").batch(g)
+    with pytest.raises(ValueError, match="give it a graph"):
+        TrafficSpec.parse("server_permutation").batch(6)
+    with pytest.raises(ValueError, match="not a generator parameter"):
+        TrafficSpec.parse("uniform:servers=3")
+
+
 # ------------------------------------------------------------------- spec
 
 def test_spec_parse_describe_round_trip():
     for text in ("uniform", "hotspot:zipf_a=1.4",
                  "permutation:flows=4096,seed=2",
-                 "bursty:duty=0.25,rate=0.5,samples=16,sync=0"):
+                 "bursty:duty=0.25,rate=0.5,samples=16,sync=0",
+                 "server_permutation:samples=8,seed=5"):
         spec = TrafficSpec.parse(text)
         again = TrafficSpec.parse(spec.describe())
         assert again == spec
@@ -240,6 +313,70 @@ def test_evaluate_traffic_batch_metrics():
     assert out["avg_hops"][0] == pytest.approx(8.0)
     assert out["dropped_demand_frac"][0] == 0.0
     assert out["demand_total"][0] == pytest.approx(16.0)
+
+
+def _enumerated_loads(g, demand):
+    """Directed ECMP loads by listing every shortest path of every pair and
+    giving each an equal share of the pair's demand (tiny graphs only)."""
+    adj = g.adjacency_dense(np.float64)
+    nbrs = [np.flatnonzero(adj[u]) for u in range(g.n)]
+    loads = np.zeros((g.n, g.n))
+    for s in range(g.n):
+        dist = np.full(g.n, -1)
+        dist[s] = 0
+        frontier = [s]
+        while frontier:
+            nxt = []
+            for u in frontier:
+                for w in nbrs[u]:
+                    if dist[w] < 0:
+                        dist[w] = dist[u] + 1
+                        nxt.append(w)
+            frontier = nxt
+
+        def paths_to(t):
+            if t == s:
+                return [[s]]
+            return [p + [t] for u in nbrs[t] if dist[u] == dist[t] - 1
+                    for p in paths_to(u)]
+
+        for t in np.flatnonzero(demand[s]):
+            if t == s or dist[t] < 0:
+                continue
+            paths = paths_to(t)
+            for p in paths:
+                for u, v in zip(p, p[1:]):
+                    loads[u, v] += demand[s, t] / len(paths)
+    return loads
+
+
+@pytest.mark.parametrize("graph", sorted(SERVER_GRAPHS))
+def test_evaluate_traffic_batch_server_permutation(graph):
+    g = SERVER_GRAPHS[graph]()
+    spec = "server_permutation:samples=3,seed=11"
+    kernel = evaluate_traffic_batch(g, spec)
+    host = evaluate_traffic_batch(g, spec, use_kernel=False)
+    demand = TrafficSpec.parse(spec).batch(g)
+    dist = wavefront_dist_mult(g.adjacency_dense())[0]
+    for i in range(3):
+        loads = _enumerated_loads(g, demand[i])
+        used = np.sort(loads[loads > 0])
+        routed = np.where(np.isfinite(dist) & (dist > 0), demand[i], 0.0)
+        ranks = [used[int(np.round(q * (len(used) - 1)))]
+                 for q in (0.5, 0.9, 0.99)]
+        want = {
+            "max_link_load": used.max(), "tput_lb": 1.0 / used.max(),
+            "mean_link_load": used.mean(), "p50_link_load": ranks[0],
+            "p90_link_load": ranks[1], "p99_link_load": ranks[2],
+            "links_used_frac": len(used) / (2 * g.num_edges),
+            "avg_hops": (routed * np.where(routed > 0, dist, 0)).sum()
+            / routed.sum(),
+            "demand_total": float(g.num_servers),
+            "dropped_demand_frac": np.trace(demand[i]) / g.num_servers,
+        }
+        for key in traffic.TRAFFIC_METRICS:
+            assert host[key][i] == pytest.approx(want[key], rel=1e-12), key
+            assert kernel[key][i] == pytest.approx(want[key], rel=1e-5), key
 
 
 def test_evaluate_traffic_batch_drops_unroutable_demand():
