@@ -355,7 +355,7 @@ def _ecmp_all_pairs_device(dist: np.ndarray, mult: np.ndarray,
 
 def ecmp_demand_loads(dist: np.ndarray, mult: np.ndarray, adj: np.ndarray,
                       demand: np.ndarray, product: Optional[Callable] = None,
-                      use_kernel: bool = True) -> np.ndarray:
+                      use_kernel: bool = True, *, device: bool = False):
     """Directed ECMP link loads of *arbitrary* (stacked) demand, O(diameter).
 
     The demand-weighted generalization of :func:`ecmp_all_pairs_loads`:
@@ -376,7 +376,10 @@ def ecmp_demand_loads(dist: np.ndarray, mult: np.ndarray, adj: np.ndarray,
     default runs the whole accumulation device-resident
     (`analysis.wavefront.ecmp_loads_device` with its weighted variant);
     ``use_kernel=False`` (or an explicit ``product``) is the f64 host
-    oracle. Returns the directed ``(.., n, n)`` load matrix.
+    oracle. Returns the directed ``(.., n, n)`` load matrix: host float64,
+    or with ``device=True`` on the kernel path the device float32 loads,
+    sliced on the device and never downloaded (the host oracle ignores
+    ``device``).
     """
     dist = np.asarray(dist)
     mult = np.asarray(mult)
@@ -400,7 +403,7 @@ def ecmp_demand_loads(dist: np.ndarray, mult: np.ndarray, adj: np.ndarray,
             adj = np.ascontiguousarray(np.broadcast_to(adj, shape))
             demand = np.ascontiguousarray(np.broadcast_to(demand, shape))
     if product is None and use_kernel:
-        return _ecmp_demand_device(dist, mult, adj, demand)
+        return _ecmp_demand_device(dist, mult, adj, demand, device)
     if product is None:
         product = count_product(use_kernel)
     finite = np.isfinite(dist)
@@ -464,9 +467,10 @@ def _ecmp_demand_host_shared(dist: np.ndarray, mult: np.ndarray,
 
 
 def _ecmp_demand_device(dist: np.ndarray, mult: np.ndarray, adj: np.ndarray,
-                        demand: np.ndarray) -> np.ndarray:
+                        demand: np.ndarray, device: bool = False):
     """Pad all four operands -> weighted device Brandes -> sliced loads,
-    each seam a ``traffic.*`` span (`repro.transfers`)."""
+    each seam a ``traffic.*`` span (`repro.transfers`); ``device`` keeps
+    the sliced loads on the device."""
     from ... import transfers
     from ..analysis.wavefront import ecmp_loads_device, pad_block, pad_operand
 
@@ -485,10 +489,12 @@ def _ecmp_demand_device(dist: np.ndarray, mult: np.ndarray, adj: np.ndarray,
                               operand(adj, 0.0, "traffic_adjacency"),
                               demand=operand(demand, 0.0, "traffic_demand"),
                               block=block)
+    sl = (Ellipsis, slice(None, n), slice(None, n))
+    if device:
+        return loads[sl]
     loads = transfers.download(transfers.wait(loads, "traffic"), "traffic",
                                "traffic_loads")
     with obs.span("traffic.host"):
-        sl = (Ellipsis, slice(None, n), slice(None, n))
         return loads[sl].astype(np.float64)
 
 

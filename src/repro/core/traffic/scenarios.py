@@ -5,9 +5,10 @@
 or raw matrices) through ONE demand-weighted Brandes accumulation
 (`routing.assign.ecmp_demand_loads`, the stacked device engine behind
 `resilience.degradation`) and reduces per-matrix congestion metrics with
-vectorized masked reductions — no per-matrix Python loop anywhere on the
-device path (``mask_chunk`` only splits oversized batches to bound device
-memory, reusing the resilience chunk budget).
+vectorized masked reductions over the fabric's directed-link cells — no
+per-matrix Python loop anywhere on the device path (``mask_chunk`` only
+splits oversized batches to bound device memory, reusing the resilience
+chunk budget).
 
 Per-matrix metrics (all defined on partitioned graphs; the
 unreachable-demand contract lives in `traffic.spec`):
@@ -33,6 +34,7 @@ one batched pass across the whole rate grid x sample stack.
 """
 from __future__ import annotations
 
+import functools
 from typing import Dict, Optional, Tuple, Union
 
 import numpy as np
@@ -94,40 +96,94 @@ def _dist_mult(adj: np.ndarray, use_kernel: bool
     return (dist, mult) if batched else (dist[0], mult[0])
 
 
-def _traffic_metrics(loads: np.ndarray, dist: np.ndarray,
-                     demand: np.ndarray, n_links: int,
+def _demand_weights(dist: np.ndarray
+                    ) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
+    """``(off, dropped, hops)`` from (.., n, n) dist: the routable cells,
+    and the float64 weights of the demand sums, 1.0 where demand is dropped
+    (diagonal and unreachable) and the hop count where it routes."""
+    off = np.isfinite(dist) & (dist > 0)           # self-demand never routes
+    return (off, np.where(off, 0.0, 1.0),
+            np.where(off, dist, 0.0).astype(np.float64))
+
+
+def _per_sample_dot(demand: np.ndarray, w: np.ndarray) -> np.ndarray:
+    """(S,) sums of each demand matrix against the (n, n) or (S, n, n)
+    cell weights ``w``: a float64 mat-vec, no (S, n, n) temporary."""
+    d = demand.reshape(len(demand), -1)
+    if w.ndim == 2:
+        return d @ w.reshape(-1)
+    return np.einsum("sk,sk->s",
+                     *np.broadcast_arrays(d, w.reshape(len(w), -1)))
+
+
+def _traffic_metrics(link: np.ndarray, demand: np.ndarray,
+                     weights: Tuple[np.ndarray, np.ndarray], n_links: int,
                      capacity: float) -> Dict[str, np.ndarray]:
-    """Per-sample congestion metrics from (C, n, n) loads/dist/demand."""
+    """Per-sample congestion metrics from (S, L) float64 loads on the
+    fabric's directed-link cells (every other cell of ``adj * acc`` is 0)
+    and the (S or 1, n, n) demand against ``weights`` (`_demand_weights`)."""
     from ..resilience.degradation import _masked_mean, _masked_percentiles
 
-    s, n, _ = loads.shape
-    offered = np.broadcast_to(np.asarray(demand, np.float64), loads.shape)
-    off = np.isfinite(dist) & (dist > 0)           # self-demand never routes
-    routed = np.where(off, offered, 0.0)
-    total = offered.reshape(s, -1).sum(1)
-    routed_sum = routed.reshape(s, -1).sum(1)
-    dropped = np.where(total > 0, 1.0 - routed_sum / np.maximum(total, 1e-300),
-                       0.0)
-    peak = loads.reshape(s, -1).max(1)
+    s = len(link)
+    demand = np.asarray(demand, np.float64)
+    total = np.broadcast_to(demand.reshape(len(demand), -1).sum(1), (s,))
+    # the dropped volume is summed itself, so it is exactly 0 where nothing
+    # drops, and the routed volume is what is left of the offered
+    lost = _per_sample_dot(demand, weights[0])
+    routed_sum = total - lost
+    dropped = np.where(total > 0, lost / np.maximum(total, 1e-300), 0.0)
+    peak = link.max(1, initial=0.0)
     tput = np.where((routed_sum > 0) & (peak > 0),
                     capacity / np.maximum(peak, 1e-300), 0.0)
-    pos = loads > 0
-    p50, p90, p99 = _masked_percentiles(loads, pos, (0.5, 0.9, 0.99))
-    hops = np.where(off, routed * np.where(off, dist, 0.0),
-                    0.0).reshape(s, -1).sum(1)
+    pos = link > 0
+    p50, p90, p99 = _masked_percentiles(link, pos, (0.5, 0.9, 0.99))
+    hops = _per_sample_dot(demand, weights[1])
     return {
         "max_link_load": peak,
         "tput_lb": tput,
-        "mean_link_load": _masked_mean(loads, pos),
+        "mean_link_load": _masked_mean(link, pos),
         "p50_link_load": p50,
         "p90_link_load": p90,
         "p99_link_load": p99,
-        "links_used_frac": pos.reshape(s, -1).sum(1) / max(n_links, 1),
+        "links_used_frac": pos.sum(1) / max(n_links, 1),
         "avg_hops": np.where(routed_sum > 0,
                              hops / np.maximum(routed_sum, 1e-300), 0.0),
-        "demand_total": total,
+        "demand_total": np.array(total),
         "dropped_demand_frac": dropped,
     }
+
+
+@functools.lru_cache(maxsize=None)
+def _gather_fn():
+    import jax
+
+    def gather(loads, cells):
+        # by row and column: a flat reshape of the stack would copy it into
+        # another layout on a TPU first
+        n = loads.shape[-1]
+        return loads[:, cells // n, cells % n]
+
+    return jax.jit(gather)
+
+
+def _link_loads(loads, cells: np.ndarray, device_cells=None) -> np.ndarray:
+    """(C, L) float64 values of (C, n, n) ``loads`` at the flat ``cells``.
+
+    Device loads are gathered where they are, at the uploaded
+    ``device_cells``, and only the (C, L) float32 link loads come down
+    (``traffic.wait``, ``traffic.d2h``); host loads are indexed in place.
+    """
+    from ... import transfers
+
+    if isinstance(loads, np.ndarray):
+        with obs.span("traffic.host"):
+            return loads.reshape(len(loads), -1)[:, cells].astype(
+                np.float64, copy=False)
+    link = _gather_fn()(loads, device_cells)
+    link = transfers.download(transfers.wait(link, "traffic"), "traffic",
+                              "traffic_link_loads")
+    with obs.span("traffic.host"):
+        return link.astype(np.float64)
 
 
 def evaluate_traffic_batch(g: Graph, demand: DemandLike,
@@ -146,8 +202,12 @@ def evaluate_traffic_batch(g: Graph, demand: DemandLike,
 
     Traced, demand generation is the ``demand.host`` span and the passes
     run inside ``traffic.scenario``, whose ``products`` attribute counts
-    the weighted counting products (2 per BFS level per matrix).
+    the weighted counting products (2 per BFS level per matrix) and
+    ``link_cells`` the directed-link cells each matrix is reduced over.
+    On the kernel path each pass's loads stay on the device and only
+    their link cells come down.
     """
+    from ... import transfers
     from ..resilience.degradation import _auto_chunk
     from ..routing.assign import ecmp_demand_loads
 
@@ -155,19 +215,28 @@ def evaluate_traffic_batch(g: Graph, demand: DemandLike,
     s, n = len(batch), g.n
     with obs.span("traffic.host"):
         adj = g.adjacency_dense()
+        # loads are adj * acc: only the nonzero adjacency cells carry any
+        cells = np.flatnonzero(adj)
     if dist is None or mult is None:
         dist, mult = _dist_mult(adj, use_kernel)
     if mask_chunk is None:
         mask_chunk = _auto_chunk(n, s)
     parts = []
     with obs.span("traffic.scenario", cat="traffic", demand=label,
-                  samples=s, routers=n, mask_chunk=mask_chunk) as sp:
+                  samples=s, routers=n, mask_chunk=mask_chunk,
+                  link_cells=len(cells)) as sp:
+        with obs.span("traffic.host"):
+            weights = _demand_weights(dist)[1:]
+        device_cells = transfers.upload(
+            cells.astype(np.int32), "traffic",
+            "traffic_link_cells") if use_kernel else None
         for lo in range(0, s, mask_chunk):
             d = batch[lo:lo + mask_chunk]
             loads = ecmp_demand_loads(dist, mult, adj, d,
-                                      use_kernel=use_kernel)
+                                      use_kernel=use_kernel, device=True)
+            link = _link_loads(loads, cells, device_cells)
             with obs.span("traffic.host"):
-                parts.append(_traffic_metrics(loads, dist[None], d,
+                parts.append(_traffic_metrics(link, d, weights,
                                               2 * len(g.edges), capacity))
         with obs.span("traffic.host"):
             out = {k: np.concatenate([p[k] for p in parts])
@@ -206,6 +275,8 @@ def evaluate_traffic_failure_batch(
                          f"{s} failure masks")
     if mask_chunk is None:
         mask_chunk = _auto_chunk(n, s)
+    # the unfailed fabric's links: every masked graph's links are among them
+    cells = np.flatnonzero(g.adjacency_dense())
     parts = []
     with obs.span("traffic.cell", cat="traffic", demand=label, samples=s,
                   routers=n, mask_chunk=mask_chunk) as sp:
@@ -216,7 +287,8 @@ def evaluate_traffic_failure_batch(
                 cd, cm = _dist_mult(a, use_kernel)
             else:
                 cd, cm = dist[lo:lo + mask_chunk], mult[lo:lo + mask_chunk]
-            parts.append(_chunk_cell(g, a, d, cd, cm, use_kernel, capacity))
+            parts.append(_chunk_cell(g, a, d, cd, cm, cells, use_kernel,
+                                     capacity))
         out = {k: np.concatenate([p[k] for p in parts]) for k in parts[0]}
         sp.set(passes=len(parts),
                dropped=float(out["dropped_demand_frac"].mean()))
@@ -224,15 +296,17 @@ def evaluate_traffic_failure_batch(
 
 
 def _chunk_cell(g: Graph, adj: np.ndarray, demand: np.ndarray,
-                dist: np.ndarray, mult: np.ndarray, use_kernel: bool,
-                capacity: float) -> Dict[str, np.ndarray]:
+                dist: np.ndarray, mult: np.ndarray, cells: np.ndarray,
+                use_kernel: bool, capacity: float) -> Dict[str, np.ndarray]:
     from ..routing.assign import ecmp_demand_loads
 
     loads = ecmp_demand_loads(dist, mult, adj.astype(np.float64), demand,
                               use_kernel=use_kernel)
-    out = _traffic_metrics(loads, dist, demand, 2 * len(g.edges), capacity)
+    # a failed link carries 0, which the used-link statistics leave out
+    off, *weights = _demand_weights(dist)
+    out = _traffic_metrics(_link_loads(loads, cells), demand, weights,
+                           2 * len(g.edges), capacity)
     c, n = len(adj), g.n
-    off = np.isfinite(dist) & (dist > 0)
     out["reachable_frac"] = off.reshape(c, -1).sum(1) / max(n * (n - 1), 1)
     return out
 

@@ -135,19 +135,26 @@ def test_traffic_stage_splits_into_leaf_spans_with_exact_bytes(traced):
     (demand,) = [ev for ev in events if ev["name"] == "demand.host"]
     assert demand["ts"] + demand["dur"] <= lo
 
-    # per pass: dist, mult, adjacency and demand up, the loads down, each
-    # a (chunk, p, p) float32 stack
+    # once per call the int32 flat indices of the L directed-link cells up;
+    # per pass dist, mult, adjacency and demand up, each a (chunk, p, p)
+    # float32 stack, and only the loads on the L link cells down
     p = pad_block(g.n, batched=True)[0]
+    links = np.count_nonzero(g.adjacency_dense())
     h2d = sum(ev["args"].get("h2d_bytes", 0) for ev in inside)
     d2h = sum(ev["args"].get("d2h_bytes", 0) for ev in inside)
-    assert h2d == 4 * samples * p * p * F32
-    assert d2h == samples * p * p * F32
+    assert h2d == 4 * samples * p * p * F32 + links * 4
+    assert d2h == samples * links * F32
     snap = obs.snapshot()
-    assert snap["d2h_bytes.traffic_loads"]["value"] == d2h
+    assert snap["d2h_bytes.traffic_link_loads"]["value"] == d2h
+    assert snap["h2d_bytes.traffic_link_cells"]["value"] == links * 4
+    assert "d2h_bytes.traffic_loads" not in snap
     assert [ev["args"]["what"] for ev in inside
-            if ev["name"] == "traffic.h2d"] == [
+            if ev["name"] == "traffic.h2d"] == ["traffic_link_cells"] + [
         "traffic_dist", "traffic_mult", "traffic_adjacency",
         "traffic_demand"] * 2
+    assert [ev["args"]["what"] for ev in inside
+            if ev["name"] == "traffic.d2h"] == ["traffic_link_loads"] * 2
+    assert scenario["args"]["link_cells"] == links
     assert len([ev for ev in inside if ev["name"] == "traffic.wait"]) == 2
     assert scenario["args"]["diameter"] == diameter
     assert scenario["args"]["products"] == 2 * diameter * samples

@@ -394,6 +394,99 @@ def test_evaluate_traffic_batch_drops_unroutable_demand():
     assert out["max_link_load"][0] > 0
 
 
+def _metrics_case(case):
+    """(graph, adjacency, demand) of one stack: one shared graph, per-sample
+    failure-masked graphs, or a partitioned graph (two rings)."""
+    import repro.core.graph as G
+    from repro.core.resilience import failure_batch, failure_plan
+
+    if case == "two_rings":
+        a = _ring(8)
+        g = G.Graph(n=16, edges=np.concatenate([a.edges, a.edges + 8]),
+                    name="two-rings")
+        spec = "uniform:samples=3,seed=5"
+    else:
+        g = topo.make("slimfly", q=5)
+        spec = "server_permutation:samples=3,seed=4"
+    adj = g.adjacency_dense()
+    if case == "failure_masked":
+        adj = failure_batch(failure_plan(g, samples=3, seed=1), 12).adjacency
+    return g, adj, TrafficSpec.parse(spec).batch(g)
+
+
+def _full_matrix_metrics(loads, dist, demand, n_links):
+    """The metrics reduced over every cell of (S, n, n) float64 loads."""
+    s = len(loads)
+    offered = np.broadcast_to(demand, loads.shape)
+    off = np.isfinite(dist) & (dist > 0)
+    routed = np.where(off, offered, 0.0)
+    total = offered.reshape(s, -1).sum(1)
+    routed_sum = routed.reshape(s, -1).sum(1)
+    peak = loads.reshape(s, -1).max(1)
+    pos = loads > 0
+    cnt = pos.reshape(s, -1).sum(1)
+    ranked = np.sort(np.where(pos, loads, np.inf).reshape(s, -1), axis=1)
+    rank = {q: np.where(cnt > 0, ranked[np.arange(s), np.round(
+        q * np.maximum(cnt - 1, 0)).astype(int)], 0.0)
+        for q in (0.5, 0.9, 0.99)}
+    hops = np.where(off, routed * np.where(off, dist, 0.0), 0.0)
+    return {
+        "max_link_load": peak,
+        "tput_lb": np.where((routed_sum > 0) & (peak > 0), 1.0 / peak, 0.0),
+        "mean_link_load": np.where(pos, loads, 0.0).reshape(s, -1).sum(1)
+        / np.maximum(cnt, 1),
+        "p50_link_load": rank[0.5], "p90_link_load": rank[0.9],
+        "p99_link_load": rank[0.99],
+        "links_used_frac": cnt / n_links,
+        "avg_hops": hops.reshape(s, -1).sum(1) / routed_sum,
+        "demand_total": total,
+        "dropped_demand_frac": 1.0 - routed_sum / total,
+    }
+
+
+@pytest.mark.parametrize("path", ["host", "device"])
+@pytest.mark.parametrize("case", ["shared", "failure_masked", "two_rings"])
+def test_link_cell_metrics_match_full_matrix_formula(case, path):
+    import jax.numpy as jnp
+
+    from repro.core.traffic import scenarios
+
+    g, adj, demand = _metrics_case(case)
+    dist, mult = scenarios._dist_mult(adj, True)
+    cells = np.flatnonzero(g.adjacency_dense())
+    if path == "host":
+        loads = assign.ecmp_demand_loads(dist, mult, adj, demand,
+                                         use_kernel=False)
+        link = scenarios._link_loads(loads, cells)
+    else:
+        # the device loads are the default path's, bit for bit
+        kept = assign.ecmp_demand_loads(dist, mult, adj, demand, device=True)
+        loads = assign.ecmp_demand_loads(dist, mult, adj, demand)
+        assert kept.shape == demand.shape and kept.dtype == np.float32
+        np.testing.assert_array_equal(np.asarray(kept).astype(np.float64),
+                                      loads)
+        link = scenarios._link_loads(kept, cells,
+                                     jnp.asarray(cells, np.int32))
+    if case == "failure_masked":
+        # every masked graph lost links, which now carry nothing
+        assert np.all(np.count_nonzero(adj.reshape(len(adj), -1), 1)
+                      < len(cells))
+    n_links = 2 * g.num_edges
+    got = scenarios._traffic_metrics(link, demand,
+                                     scenarios._demand_weights(dist)[1:],
+                                     n_links, 1.0)
+    want = _full_matrix_metrics(loads, dist, demand, n_links)
+    if case == "two_rings":
+        assert np.all(want["dropped_demand_frac"] > 0.4)
+    for key in ("max_link_load", "tput_lb", "p50_link_load",
+                "p90_link_load", "p99_link_load", "links_used_frac"):
+        np.testing.assert_array_equal(got[key], want[key], err_msg=key)
+    for key in ("mean_link_load", "avg_hops", "demand_total",
+                "dropped_demand_frac"):
+        np.testing.assert_allclose(got[key], want[key], rtol=1e-12,
+                                   atol=0, err_msg=key)
+
+
 def test_traffic_failure_batch_unfailed_matches_unfailed_engine():
     g = topo.make("jellyfish", n=30, r=6, seed=1)
     dem = TrafficSpec.parse("hotspot:samples=4,seed=2").batch(g)
