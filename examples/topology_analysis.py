@@ -127,8 +127,7 @@ for fam in FAMILIES:
     wl = W.Workload(pairs=spec.pairs(g), name=spec.describe())
     tr = W.evaluate_workload(g, wl, dist=eng.distances(), mult=mult)
     demand = wl.demand_matrix(g)
-    # f64 BLAS path for the model columns: the walkthrough favours turnaround;
-    # the Pallas counting-kernel path is timed in benchmarks/bench_collectives
+    # f64 BLAS path for the model columns: the walkthrough favours turnaround
     vlb = R.ValiantVLB.from_engine(eng, use_kernel=False)
     slack1 = R.SlackRouting.from_engine(eng, slack=1, use_kernel=False)
     print(f"{fam:<11}{g.n:>8}{rep['diameter']:>6}"

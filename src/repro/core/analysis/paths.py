@@ -160,8 +160,7 @@ def tropical_count_relaxation(g: Graph, use_kernel: bool = True
     ``diameter`` steps converge. This was the default kernel path before the
     device-resident wavefront engine; it is kept verbatim — per-step
     ``np.array`` copies, host diagonal re-pinning, host convergence check —
-    both as an independent correctness anchor and as the host-loop baseline
-    the perf harness (`benchmarks/run.py --baseline`) measures against.
+    as an independent correctness anchor.
     """
     import jax.numpy as jnp
     from ... import kernels

@@ -53,7 +53,8 @@ import jax.numpy as jnp
 from ... import obs, transfers
 from ...device import resolve_interpret
 
-__all__ = ["wavefront_dist_mult", "dist_mult_device", "ecmp_loads_device",
+__all__ = ["wavefront_dist_mult", "batched_dist_mult", "batched_count",
+           "dist_mult_device", "ecmp_loads_device",
            "slack_counts_device", "squaring_apsp_device", "pad_block",
            "pad_operand", "telemetry_attrs"]
 
@@ -357,6 +358,54 @@ def wavefront_dist_mult(adj: np.ndarray, block: Optional[int] = None,
             with obs.span("wavefront.host"):
                 _warn_if_inexact(mult, use_kernel=True)
     return dist, mult
+
+
+def batched_count(use_kernel: bool):
+    """Stacked (+, x) product over a leading axis: the batched Pallas
+    COUNTING kernel, or the f64 numpy oracle."""
+    if use_kernel:
+        from ... import kernels
+
+        return lambda a, b: np.asarray(
+            kernels.ops.batched_count_matmul(jnp.asarray(a), jnp.asarray(b)))
+    return lambda a, b: np.asarray(a, np.float64) @ np.asarray(b, np.float64)
+
+
+def batched_dist_mult(adj: np.ndarray, use_kernel: bool = True
+                      ) -> Tuple[np.ndarray, np.ndarray]:
+    """Hop distances and float64 shortest-path multiplicities of one
+    (n, n) or stacked (B, n, n) adjacency: the wavefront engine, or its f64
+    host oracle.
+
+    ``use_kernel`` runs :func:`wavefront_dist_mult`. Otherwise the same
+    Brandes frontier identity runs as a host loop, one stacked f64 counting
+    product per BFS level: ``x_k = F_k @ A`` extends the level-k
+    multiplicity frontier by one hop, and any pair first reached at level
+    k+1 has ``sigma = x_k`` there. The loop stops as soon as a level
+    reaches no new pair. Padding rows are isolated phantoms: their frontier
+    never grows.
+    """
+    if use_kernel:
+        dist, mult = wavefront_dist_mult(adj)
+        with obs.span("wavefront.host"):
+            return dist, mult.astype(np.float64)
+    stacked = adj if adj.ndim == 3 else adj[None]
+    count = batched_count(False)
+    nb, p, _ = stacked.shape
+    dist = np.full((nb, p, p), _INF, np.float32)
+    idx = np.arange(p)
+    dist[:, idx, idx] = 0.0
+    mult = np.where(dist == 0, 1.0, 0.0)
+    frontier = mult.astype(stacked.dtype)
+    for level in range(1, p + 1):
+        x = count(frontier, stacked)
+        new = (x > 0) & ~np.isfinite(dist)
+        if not new.any():
+            break
+        dist[new] = level
+        mult = np.where(new, x, mult)
+        frontier = np.where(new, x, 0.0).astype(stacked.dtype)
+    return (dist, mult) if adj.ndim == 3 else (dist[0], mult[0])
 
 
 @functools.lru_cache(maxsize=None)

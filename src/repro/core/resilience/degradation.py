@@ -49,6 +49,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 import numpy as np
 
 from ... import obs
+from ..analysis.wavefront import batched_count, batched_dist_mult
 from ..graph import Graph
 from .faults import failure_batch, failure_plan, rate_to_k
 
@@ -108,9 +109,7 @@ def _batched_slack_means(adj: np.ndarray, dist: np.ndarray,
     semantics: +1 counts are exact simple paths, +2 counts subtract the
     one-bounce correction and clamp at zero.
     """
-    from ..sweep import _batched_count
-
-    product = _batched_count(use_kernel)
+    product = batched_count(use_kernel)
     s, n, _ = adj.shape
     deg = adj.sum(axis=-1)                                   # (S, n)
     finite = np.isfinite(dist)
@@ -149,15 +148,7 @@ def _eval_stack(adj: np.ndarray, n: int, use_kernel: bool, slack: bool,
     broadcasts) via `routing.assign.ecmp_demand_loads` and adds the
     ``dropped_demand_frac`` metric per the `core.traffic.spec` contract.
     """
-    if use_kernel:
-        from ..analysis.wavefront import wavefront_dist_mult
-
-        dist, mult = wavefront_dist_mult(adj)
-        mult = mult.astype(np.float64)
-    else:
-        from ..sweep import _batched_count, batched_dist_mult
-
-        dist, mult = batched_dist_mult(adj, _batched_count(False))
+    dist, mult = batched_dist_mult(adj, use_kernel)
     s = len(adj)
     off = np.isfinite(dist) & (dist > 0)
     cnt = off.reshape(s, -1).sum(1)

@@ -37,8 +37,6 @@ use this helper, so their ``*_imbalance`` ratios are directly comparable.
 """
 from __future__ import annotations
 
-import contextlib
-import contextvars
 from typing import Callable, Dict, Iterable, Optional, Sequence, Tuple
 
 import numpy as np
@@ -49,35 +47,7 @@ from ..graph import Graph
 __all__ = ["demand_matrix", "ecmp_link_loads", "ecmp_all_pairs_loads",
            "ecmp_demand_loads", "walk_slack_link_loads",
            "directed_to_link_loads", "link_load_stats", "count_product",
-           "product_names", "padded_neighbors", "sample_columns",
-           "mask_unreachable_demand"]
-
-#: (stage, left operand, right operand, product): the names under which a
-#: kernel-path counting product reports its transfers (`repro.transfers`)
-_PRODUCT_NAMES = contextvars.ContextVar(
-    "count_product_names",
-    default=("count", "count_lhs", "count_rhs", "count_out"))
-
-
-def product_names(stage: str, lhs: str, rhs: str, out: str):
-    """Context naming the transfers of the counting products called inside
-    it: spans ``<stage>.h2d`` / ``.wait`` / ``.d2h`` and byte counters
-    ``h2d_bytes.<lhs>``, ``h2d_bytes.<rhs>``, ``d2h_bytes.<out>``. With
-    tracing off it names nothing. A context and not an argument, so that
-    `count_product` keeps the one shape every caller, and every stand-in
-    for it, has: ``count_product(use_kernel)(a, b)``."""
-    if not obs.enabled():
-        return contextlib.nullcontext()
-    return _named_products((stage, lhs, rhs, out))
-
-
-@contextlib.contextmanager
-def _named_products(names):
-    token = _PRODUCT_NAMES.set(names)
-    try:
-        yield
-    finally:
-        _PRODUCT_NAMES.reset(token)
+           "padded_neighbors", "sample_columns", "mask_unreachable_demand"]
 
 
 def count_product(use_kernel: bool) -> Callable[[np.ndarray, np.ndarray],
@@ -85,19 +55,20 @@ def count_product(use_kernel: bool) -> Callable[[np.ndarray, np.ndarray],
     """(+, x) matmul: Pallas COUNTING kernel, or f64 numpy oracle.
 
     The kernel path uploads both operands, waits for the product and
-    downloads it, each through `repro.transfers` under the names
-    `product_names` gives."""
+    downloads it, each through `repro.transfers`: spans ``count.h2d`` /
+    ``.wait`` / ``.d2h``, byte counters ``h2d_bytes.count_lhs``,
+    ``h2d_bytes.count_rhs`` and ``d2h_bytes.count_out``."""
     if use_kernel:
         import jax.numpy as jnp
 
         from ... import kernels, transfers
 
         def product(a, b):
-            stage, lhs, rhs, out = _PRODUCT_NAMES.get()
             c = kernels.ops.count_matmul(
-                transfers.upload(a, stage, lhs, jnp.float32),
-                transfers.upload(b, stage, rhs, jnp.float32))
-            return transfers.download(transfers.wait(c, stage), stage, out)
+                transfers.upload(a, "count", "count_lhs", jnp.float32),
+                transfers.upload(b, "count", "count_rhs", jnp.float32))
+            return transfers.download(transfers.wait(c, "count"), "count",
+                                      "count_out")
 
         return product
     return lambda a, b: np.asarray(a, np.float64) @ np.asarray(b, np.float64)
@@ -294,68 +265,15 @@ def ecmp_all_pairs_loads(dist: np.ndarray, mult: np.ndarray, adj: np.ndarray,
     single-device accumulation to f32 round-off.
     """
     if product is None and use_kernel:
-        return _ecmp_all_pairs_device(dist, mult, adj, mesh)
+        return _ecmp_device("ecmp", dist, mult, adj, mesh=mesh)
     if product is None:
         product = count_product(use_kernel)
-    finite = np.isfinite(dist)
-    diam = int(dist[finite].max()) if finite.any() else 0
-    sigma_inv = np.where(finite & (mult > 0), 1.0 / np.where(mult > 0, mult, 1.0), 0.0)
-    delta = np.zeros_like(sigma_inv)
-    acc = np.zeros_like(sigma_inv)
-    for a in range(diam - 1, -1, -1):
-        z = np.where(dist == a + 1, (1.0 + delta) * sigma_inv, 0.0)
-        f_a = np.where(dist == a, mult, 0.0)
-        acc = acc + np.asarray(product(np.swapaxes(f_a, -1, -2), z))
-        delta = np.where(dist == a, mult * np.asarray(product(z, adj)), delta)
-    return adj * acc
-
-
-def _ecmp_all_pairs_device(dist: np.ndarray, mult: np.ndarray,
-                           adj: np.ndarray, mesh=None) -> np.ndarray:
-    """Pad -> device-resident Brandes accumulation -> sliced host loads.
-
-    With a multi-device ``mesh`` the accumulation runs shard-local over
-    source rows (`distributed.ecmp_loads_sharded`); jit reshards the
-    replicated uploads onto the mesh per the engine's in_specs.
-    """
-    from ... import transfers
-    from ..analysis.wavefront import ecmp_loads_device, pad_block, pad_operand
-
-    n = np.asarray(dist).shape[-1]
-    batched = np.asarray(dist).ndim == 3
-    sharded = mesh is not None and mesh.size > 1
-    if sharded:
-        from ..analysis.distributed import (ROW_AXIS, ecmp_loads_sharded,
-                                            pad_block_sharded)
-
-        p, _, block = pad_block_sharded(n, mesh.shape[ROW_AXIS],
-                                        batched=batched)
-    else:
-        p, block = pad_block(n, batched=batched)
-
-    def operand(x, fill, what):
-        # pad and upload one operand at a time: one padded host copy lives
-        with obs.span("ecmp.host"):
-            x = pad_operand(x, p, fill)
-        return transfers.upload(x, "ecmp", what)
-
-    operands = (operand(dist, np.inf, "ecmp_dist"),
-                operand(mult, 0.0, "ecmp_mult"),
-                operand(adj, 0.0, "ecmp_adjacency"))
-    if sharded:
-        loads = ecmp_loads_sharded(*operands, mesh, block=block)
-    else:
-        loads = ecmp_loads_device(*operands, block=block)
-    loads = transfers.download(transfers.wait(loads, "ecmp"), "ecmp",
-                               "ecmp_loads")
-    with obs.span("ecmp.host"):
-        sl = (Ellipsis, slice(None, n), slice(None, n))
-        return loads[sl].astype(np.float64)
+    return _brandes_host(dist, mult, adj, 1.0, product)
 
 
 def ecmp_demand_loads(dist: np.ndarray, mult: np.ndarray, adj: np.ndarray,
-                      demand: np.ndarray, product: Optional[Callable] = None,
-                      use_kernel: bool = True, *, device: bool = False):
+                      demand: np.ndarray, use_kernel: bool = True, *,
+                      device: bool = False):
     """Directed ECMP link loads of *arbitrary* (stacked) demand, O(diameter).
 
     The demand-weighted generalization of :func:`ecmp_all_pairs_loads`:
@@ -375,37 +293,37 @@ def ecmp_demand_loads(dist: np.ndarray, mult: np.ndarray, adj: np.ndarray,
     (the traffic x failure grid) against per-sample demand. The kernel
     default runs the whole accumulation device-resident
     (`analysis.wavefront.ecmp_loads_device` with its weighted variant);
-    ``use_kernel=False`` (or an explicit ``product``) is the f64 host
-    oracle. Returns the directed ``(.., n, n)`` load matrix: host float64,
-    or with ``device=True`` on the kernel path the device float32 loads,
-    sliced on the device and never downloaded (the host oracle ignores
-    ``device``).
+    ``use_kernel=False`` is the f64 host oracle. Returns the directed
+    ``(.., n, n)`` load matrix: host float64, or with ``device=True`` on
+    the kernel path the device float32 loads, sliced on the device and
+    never downloaded (the host oracle ignores ``device``).
     """
     dist = np.asarray(dist)
     mult = np.asarray(mult)
     adj = np.asarray(adj)
     demand = np.asarray(demand, np.float64)
-    batched = max(dist.ndim, demand.ndim) == 3
-    if batched:
+    if max(dist.ndim, demand.ndim) == 3:
         shape = np.broadcast_shapes(dist.shape, mult.shape, adj.shape,
                                     demand.shape)
-        if product is None and not use_kernel and dist.ndim == 2 \
-                and mult.ndim == 2 and adj.ndim == 2:
-            # host fast path: one shared graph, stacked demand — fuse each
-            # level's S small products into single (n, S*n) / (S*n, n)
-            # GEMMs (the counting product on floats IS matmul)
-            return _ecmp_demand_host_shared(
-                dist, mult, adj,
-                np.ascontiguousarray(np.broadcast_to(demand, shape)))
         with obs.span("traffic.host"):
             dist = np.ascontiguousarray(np.broadcast_to(dist, shape))
             mult = np.ascontiguousarray(np.broadcast_to(mult, shape))
             adj = np.ascontiguousarray(np.broadcast_to(adj, shape))
             demand = np.ascontiguousarray(np.broadcast_to(demand, shape))
-    if product is None and use_kernel:
-        return _ecmp_demand_device(dist, mult, adj, demand, device)
-    if product is None:
-        product = count_product(use_kernel)
+    if use_kernel:
+        return _ecmp_device("traffic", dist, mult, adj, demand, keep=device)
+    return _brandes_host(dist, mult, adj, demand, count_product(False))
+
+
+def _brandes_host(dist: np.ndarray, mult: np.ndarray, adj: np.ndarray,
+                  demand, product: Callable) -> np.ndarray:
+    """The host Brandes backward recurrence behind both ECMP entry points.
+
+    ``Z_a = (demand + delta) / sigma`` on the level set ``d = a + 1``;
+    ``demand`` is 1.0 for uniform all-pairs demand or a demand array that
+    broadcasts against ``dist``. The level masks exclude the diagonal and
+    unreachable pairs, so their demand never routes.
+    """
     finite = np.isfinite(dist)
     diam = int(dist[finite].max()) if finite.any() else 0
     sigma_inv = np.where(finite & (mult > 0),
@@ -420,81 +338,56 @@ def ecmp_demand_loads(dist: np.ndarray, mult: np.ndarray, adj: np.ndarray,
     return adj * acc
 
 
-def _ecmp_demand_host_shared(dist: np.ndarray, mult: np.ndarray,
-                             adj: np.ndarray, demand: np.ndarray
-                             ) -> np.ndarray:
-    """Shared-graph f64 Brandes over an (S, n, n) demand stack.
+def _ecmp_device(stage: str, dist: np.ndarray, mult: np.ndarray,
+                 adj: np.ndarray, demand: Optional[np.ndarray] = None,
+                 mesh=None, keep: bool = False):
+    """Pad -> device Brandes accumulation -> sliced loads: the one upload
+    seam of both ECMP entry points.
 
-    Same recurrence as the generic host loop, but with the graph operands
-    kept 2-D: the level's ``F_a^T @ Z_s`` products collapse into one
-    ``(n, S*n)`` GEMM (samples stacked along columns) and ``Z_s @ A`` into
-    one ``(S*n, n)`` GEMM, so BLAS sees two large multiplies per BFS level
-    instead of 2S small ones and no (S, n, n) graph copies are made.
+    Each operand is padded and uploaded in turn, so one padded host copy
+    lives at a time: spans ``<stage>.host`` / ``.h2d`` / ``.wait`` /
+    ``.d2h`` and byte counters ``h2d_bytes.<stage>_<operand>``,
+    ``d2h_bytes.<stage>_loads`` (`repro.transfers`). ``demand=None`` is
+    uniform all-pairs demand. With a multi-device ``mesh`` the uniform
+    accumulation runs shard-local over source rows
+    (`distributed.ecmp_loads_sharded`); jit reshards the replicated uploads
+    onto the mesh per the engine's in_specs. ``keep`` returns the sliced
+    float32 loads on the device, never downloaded.
     """
-    s, n, _ = demand.shape
-    dist = np.asarray(dist, np.float64)
-    mult = np.asarray(mult, np.float64)
-    adj = np.asarray(adj, np.float64)
-    finite = np.isfinite(dist)
-    diam = int(dist[finite].max()) if finite.any() else 0
-    sigma_inv = np.where(finite & (mult > 0),
-                         1.0 / np.where(mult > 0, mult, 1.0), 0.0)
-    # per-level 2-D masks hoisted out of the stack loop; ``sig`` both
-    # applies 1/sigma and selects the level's cells, so no (S, n, n)
-    # ``where`` is ever materialized
-    sig = [np.where(dist == a + 1, sigma_inv, 0.0) for a in range(diam)]
-    dmul = [np.where(dist == a, mult, 0.0) for a in range(diam)]
-    out = np.empty((s, n, n), np.float64)
-    # chunk the stack so each chunk's temporaries stay cache-resident —
-    # a full 800 x n x n pass would be memory-bound on stack temporaries
-    chunk = max(1, min(s, (1 << 21) // (n * n * 8) or 1))
-    for lo in range(0, s, chunk):
-        dem = demand[lo:lo + chunk]
-        c = dem.shape[0]
-        acc = np.zeros((c, n, n), np.float64)
-        delta = np.zeros((c, n, n), np.float64)
-        for a in range(diam - 1, -1, -1):
-            # delta is only read on cells at distance a+1 (sig[a] masks the
-            # rest), so overwriting it each level is safe
-            z = (dem + delta) * sig[a]
-            z_cols = np.ascontiguousarray(
-                z.transpose(1, 0, 2)).reshape(n, c * n)
-            acc += (dmul[a].T @ z_cols).reshape(n, c, n).transpose(1, 0, 2)
-            if a:
-                delta = dmul[a] * (z.reshape(c * n, n) @ adj).reshape(c, n, n)
-        np.multiply(adj, acc, out=out[lo:lo + chunk])
-    return out
-
-
-def _ecmp_demand_device(dist: np.ndarray, mult: np.ndarray, adj: np.ndarray,
-                        demand: np.ndarray, device: bool = False):
-    """Pad all four operands -> weighted device Brandes -> sliced loads,
-    each seam a ``traffic.*`` span (`repro.transfers`); ``device`` keeps
-    the sliced loads on the device."""
     from ... import transfers
     from ..analysis.wavefront import ecmp_loads_device, pad_block, pad_operand
 
-    n = dist.shape[-1]
-    batched = dist.ndim == 3
-    p, block = pad_block(n, batched=batched)
+    n = np.shape(dist)[-1]
+    batched = np.ndim(dist) == 3
+    sharded = mesh is not None and mesh.size > 1
+    if sharded:
+        from ..analysis.distributed import (ROW_AXIS, ecmp_loads_sharded,
+                                            pad_block_sharded)
+
+        p, _, block = pad_block_sharded(n, mesh.shape[ROW_AXIS],
+                                        batched=batched)
+    else:
+        p, block = pad_block(n, batched=batched)
 
     def operand(x, fill, what):
-        # pad and upload one operand at a time: one padded host copy lives
-        with obs.span("traffic.host"):
+        with obs.span(f"{stage}.host"):
             x = pad_operand(x, p, fill)
-        return transfers.upload(x, "traffic", what)
+        return transfers.upload(x, stage, f"{stage}_{what}")
 
-    loads = ecmp_loads_device(operand(dist, np.inf, "traffic_dist"),
-                              operand(mult, 0.0, "traffic_mult"),
-                              operand(adj, 0.0, "traffic_adjacency"),
-                              demand=operand(demand, 0.0, "traffic_demand"),
-                              block=block)
+    operands = (operand(dist, np.inf, "dist"), operand(mult, 0.0, "mult"),
+                operand(adj, 0.0, "adjacency"))
+    if sharded:
+        loads = ecmp_loads_sharded(*operands, mesh, block=block)
+    else:
+        if demand is not None:
+            demand = operand(demand, 0.0, "demand")
+        loads = ecmp_loads_device(*operands, demand=demand, block=block)
     sl = (Ellipsis, slice(None, n), slice(None, n))
-    if device:
+    if keep:
         return loads[sl]
-    loads = transfers.download(transfers.wait(loads, "traffic"), "traffic",
-                               "traffic_loads")
-    with obs.span("traffic.host"):
+    loads = transfers.download(transfers.wait(loads, stage), stage,
+                               f"{stage}_loads")
+    with obs.span(f"{stage}.host"):
         return loads[sl].astype(np.float64)
 
 

@@ -13,10 +13,8 @@ instead of one per topology. Stages:
    multiplicities together (``x_k = F_k @ A``; pairs first reached at
    level k+1 get dist = k+1 and sigma = x). Hop-distance BFS needs no
    tropical products at all, and the counting semiring rides the kernel's
-   fast MXU path — this is where the sweep's speedup over looping
-   ``analyze()`` per topology comes from (the loop's engine runs general
-   min-plus squaring on the VPU path; benchmarked in
-   ``benchmarks/bench_analysis.py`` and the ``--sweep`` example);
+   fast MXU path (looping ``analyze()`` per topology instead runs general
+   min-plus squaring on the VPU path);
 2. stacked Brandes accumulation (`routing.assign.ecmp_all_pairs_loads`,
    2 products per level) -> exact expected ECMP link loads under uniform
    all-pairs demand, whose max gives the per-pair saturation-throughput
@@ -48,12 +46,12 @@ import numpy as np
 from .. import obs
 from . import costmodel
 from . import topology as topo
+from .analysis.wavefront import batched_count, batched_dist_mult
 from .graph import Graph
 from .routing.assign import ecmp_all_pairs_loads
 
-__all__ = ["equal_cost_graphs", "batched_apsp", "batched_dist_mult",
-           "sweep", "format_table", "check_families",
-           "sweep_extreme", "format_extreme_table"]
+__all__ = ["equal_cost_graphs", "batched_apsp", "sweep", "format_table",
+           "check_families", "sweep_extreme", "format_extreme_table"]
 
 _INF = np.float32(np.inf)
 
@@ -79,17 +77,6 @@ def _batched_minplus(use_kernel: bool):
         return out
 
     return oracle
-
-
-def _batched_count(use_kernel: bool):
-    if use_kernel:
-        import jax.numpy as jnp
-
-        from .. import kernels
-
-        return lambda a, b: np.asarray(
-            kernels.ops.batched_count_matmul(jnp.asarray(a), jnp.asarray(b)))
-    return lambda a, b: np.asarray(a, np.float64) @ np.asarray(b, np.float64)
 
 
 # -- equal-cost instantiation -------------------------------------------------
@@ -180,52 +167,6 @@ def _apsp_from_stack(dist: np.ndarray, minplus) -> np.ndarray:
     return dist
 
 
-def batched_dist_mult(adj: np.ndarray, count=None,
-                      max_levels: Optional[int] = None):
-    """Hop distances AND shortest-path multiplicities from one stacked
-    counting product per BFS level (Brandes' frontier identity).
-
-    ``x_k = F_k @ A`` extends the level-k multiplicity frontier by one hop;
-    any pair first reached at level k+1 has ``sigma = x_k`` there. Distances
-    fall out of *when* a pair is first reached, so hop-distance APSP needs
-    no tropical (VPU-path) products at all — every product is a counting
-    matmul on the kernel's fast MXU path. Stops as soon as a sweep makes no
-    new pair reachable (= max diameter over the stack, +1 to confirm).
-    Padding rows are isolated phantoms: their frontier never grows.
-
-    With ``count=None`` the whole loop runs device-resident
-    (`analysis.wavefront.dist_mult_device`) — one jitted `lax.while_loop`,
-    no per-level host masking. Passing an explicit ``count`` product (or a
-    ``max_levels`` cap, which the device engine does not expose) keeps the
-    host-looped reference sweep below (the wavefront engine's batched
-    oracle in the tests).
-    """
-    if count is None:
-        if max_levels is None:
-            from .analysis.wavefront import wavefront_dist_mult
-
-            dist, mult = wavefront_dist_mult(adj)
-            return dist, mult.astype(np.float64)
-        count = _batched_count(True)  # capped sweep: host loop, kernel product
-    nb, p, _ = adj.shape
-    if max_levels is None:
-        max_levels = p
-    dist = np.full((nb, p, p), _INF, np.float32)
-    idx = np.arange(p)
-    dist[:, idx, idx] = 0.0
-    mult = np.where(dist == 0, 1.0, 0.0).astype(np.float64)
-    frontier = mult.astype(adj.dtype)
-    for level in range(1, max_levels + 1):
-        x = np.asarray(count(frontier, adj))
-        new = (x > 0) & ~np.isfinite(dist)
-        if not new.any():
-            break
-        dist[new] = level
-        mult = np.where(new, x, mult)
-        frontier = np.where(new, x, 0.0).astype(adj.dtype)
-    return dist, mult
-
-
 # -- the driver ---------------------------------------------------------------
 
 def sweep(families: Optional[Sequence[str]] = None,
@@ -238,12 +179,11 @@ def sweep(families: Optional[Sequence[str]] = None,
           mesh="auto", traffic=None) -> Dict:
     """Run the equal-cost comparison; returns ``{"rows": [...], ...}``.
 
-    Pass ``graphs`` to analyze a pre-built list (the benchmarks reuse this
-    to time the batched path against a per-topology ``analyze()`` loop on
-    identical instances). With more than one jax device visible the stacked
-    chain runs row-sharded over a 1-D mesh (`analysis.distributed`):
-    ``mesh="auto"`` picks it up, an explicit Mesh pins it, None forces the
-    single-device engines.
+    Pass ``graphs`` to analyze a pre-built list instead of the equal-cost
+    set. With more than one jax device visible the stacked chain runs
+    row-sharded over a 1-D mesh (`analysis.distributed`): ``mesh="auto"``
+    picks it up, an explicit Mesh pins it, None forces the single-device
+    engines.
 
     ``traffic`` (a `core.traffic.TrafficSpec` or spec string, ``--traffic``
     on the CLI) additionally pushes that scenario's demand batch through
@@ -329,10 +269,10 @@ def sweep(families: Optional[Sequence[str]] = None,
         else:
             with obs.span("sweep.dist_mult", cat="sweep",
                           stacked=len(graphs), oracle=True):
-                count = _batched_count(use_kernel)
-                dist, mult = batched_dist_mult(adj, count)
+                dist, mult = batched_dist_mult(adj, use_kernel=False)
             with obs.span("sweep.ecmp_loads", cat="sweep", oracle=True):
-                loads = (ecmp_all_pairs_loads(dist, mult, adj, product=count)
+                loads = (ecmp_all_pairs_loads(dist, mult, adj,
+                                              product=batched_count(False))
                          if throughput else None)
 
         with obs.span("sweep.rows", cat="sweep"):
@@ -462,7 +402,7 @@ def sweep_extreme(families: Optional[Sequence[str]] = None,
     the aggregates. A family whose parameter ladder cannot reach the
     target records an ``error`` row instead of aborting the sweep — the
     table shows the gap. Returns ``{"rows": [...], ...}`` for
-    :func:`format_extreme_table` / the BENCH_8 baseline row.
+    :func:`format_extreme_table`.
     """
     from .analysis.estimator import sampled_sources_summary
 

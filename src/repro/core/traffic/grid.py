@@ -30,6 +30,7 @@ from typing import Dict, List, Optional, Sequence, Tuple, Union
 import numpy as np
 
 from ... import obs
+from ..analysis.wavefront import batched_dist_mult
 from ..graph import Graph
 from .spec import TrafficSpec, as_spec
 from .scenarios import (TRAFFIC_METRICS, evaluate_traffic_batch,
@@ -80,7 +81,6 @@ def traffic_failure_grid(
     """
     from ..resilience.faults import failure_batch, failure_plan, rate_to_k
     from ..sweep import equal_cost_graphs
-    from .scenarios import _dist_mult
 
     t0 = time.time()
     rates = sorted(float(r) for r in rates)
@@ -107,7 +107,8 @@ def traffic_failure_grid(
                 continue
             with obs.span("traffic.family", cat="traffic", family=fam,
                           routers=g.n, units=plan.n_units):
-                dist0, mult0 = _dist_mult(g.adjacency_dense(), use_kernel)
+                dist0, mult0 = batched_dist_mult(g.adjacency_dense(),
+                                                 use_kernel)
                 demands = {sp.describe(): sp.batch(g, samples=samples)
                            for sp in specs}
                 # the unfailed baseline: ONE matrix (sample 0) through the
@@ -134,7 +135,8 @@ def traffic_failure_grid(
                         continue
                     k = rate_to_k(plan, rate)
                     batch = failure_batch(plan, k)
-                    distk, multk = _dist_mult(batch.adjacency, use_kernel)
+                    distk, multk = batched_dist_mult(batch.adjacency,
+                                                     use_kernel)
                     for desc, dem in demands.items():
                         vals = evaluate_traffic_failure_batch(
                             g, dem, batch.adjacency, dist=distk, mult=multk,

@@ -40,6 +40,7 @@ from typing import Dict, Optional, Tuple, Union
 import numpy as np
 
 from ... import obs
+from ..analysis.wavefront import batched_dist_mult
 from ..graph import Graph
 from .spec import TrafficSpec, as_spec
 
@@ -77,23 +78,6 @@ def demand_batch(g: Graph, demand: DemandLike,
         raise ValueError(f"demand batch has {len(d)} samples, wanted "
                          f"{samples}")
     return d, f"matrix[{len(d)}]"
-
-
-def _dist_mult(adj: np.ndarray, use_kernel: bool
-               ) -> Tuple[np.ndarray, np.ndarray]:
-    """(Batched) dist + multiplicity, kernel or host oracle."""
-    if use_kernel:
-        from ..analysis.wavefront import wavefront_dist_mult
-
-        dist, mult = wavefront_dist_mult(adj)
-        with obs.span("traffic.host"):
-            return dist, mult.astype(np.float64)
-    from ..sweep import _batched_count, batched_dist_mult
-
-    batched = adj.ndim == 3
-    a = adj if batched else adj[None]
-    dist, mult = batched_dist_mult(a, _batched_count(False))
-    return (dist, mult) if batched else (dist[0], mult[0])
 
 
 def _demand_weights(dist: np.ndarray
@@ -218,7 +202,7 @@ def evaluate_traffic_batch(g: Graph, demand: DemandLike,
         # loads are adj * acc: only the nonzero adjacency cells carry any
         cells = np.flatnonzero(adj)
     if dist is None or mult is None:
-        dist, mult = _dist_mult(adj, use_kernel)
+        dist, mult = batched_dist_mult(adj, use_kernel)
     if mask_chunk is None:
         mask_chunk = _auto_chunk(n, s)
     parts = []
@@ -284,7 +268,7 @@ def evaluate_traffic_failure_batch(
             a = adjacency[lo:lo + mask_chunk]
             d = batch if len(batch) == 1 else batch[lo:lo + mask_chunk]
             if dist is None or mult is None:
-                cd, cm = _dist_mult(a, use_kernel)
+                cd, cm = batched_dist_mult(a, use_kernel)
             else:
                 cd, cm = dist[lo:lo + mask_chunk], mult[lo:lo + mask_chunk]
             parts.append(_chunk_cell(g, a, d, cd, cm, cells, use_kernel,
@@ -338,7 +322,7 @@ def saturation_search(g: Graph, spec: Union[str, TrafficSpec],
     base, label = demand_batch(g, spec)
     s, n = len(base), g.n
     adj = g.adjacency_dense()
-    dist, mult = _dist_mult(adj, use_kernel)
+    dist, mult = batched_dist_mult(adj, use_kernel)
     if mask_chunk is None:
         mask_chunk = _auto_chunk(n, s * max(int(grid), 2))
 

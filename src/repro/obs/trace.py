@@ -282,8 +282,7 @@ class Tracer:
         self.epoch_ns = time.perf_counter_ns()
 
     def span_summary(self) -> Dict[str, Dict[str, float]]:
-        """Aggregate completed spans by name: count + total milliseconds
-        (the compact form BENCH_N.json embeds)."""
+        """Aggregate completed spans by name: count + total milliseconds."""
         out: Dict[str, Dict[str, float]] = {}
         for ev in self.events():
             if ev.get("ph") != "X":

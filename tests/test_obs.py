@@ -1,11 +1,9 @@
-"""The observability layer itself: tracing, meters, the report CLI, and
-the bench-artifact metadata rules.
+"""The observability layer itself: tracing, meters and the report CLI.
 
 The substrate contracts: disabled tracing returns the shared no-op span
 and records nothing; enabled spans nest (per thread), export as Chrome
 trace-event JSON with the meters snapshot in ``otherData``; ``record_h2d``
-is inert when tracing is off; the trajectory gate never reads the
-``meta`` / ``spans`` subtrees. The jitted engines' disabled-path jaxpr
+is inert when tracing is off. The jitted engines' disabled-path jaxpr
 identity lives in ``test_wavefront.py`` / ``test_distributed.py``.
 """
 import json
@@ -299,36 +297,3 @@ def test_report_cli_exit_codes(tmp_path, capsys):
     garbage = tmp_path / "garbage.json"
     garbage.write_text("not json")
     assert obs_report.main([str(garbage)]) == 2
-
-
-# -- bench artifact rules -----------------------------------------------------
-
-def _bench_run():
-    sys.path.insert(0, str(REPO))
-    try:
-        from benchmarks import run as bench_run
-    finally:
-        sys.path.pop(0)
-    return bench_run
-
-
-def test_gate_ignores_meta_and_spans_subtrees():
-    bench_run = _bench_run()
-    artifact = {
-        "analyze": {"speedup": 10.0},
-        "meta": {"bogus_speedup": 1.0, "nested": {"x_speedup": 2.0}},
-        "spans": {"sweep": {"count": 1, "total_ms_speedup": 3.0}},
-    }
-    cols = bench_run._speedup_columns(artifact)
-    assert cols == {"analyze.speedup": 10.0}
-    # gate compares only the real speedup column: differing metadata
-    # between runs never produces a regression (or a shared column)
-    ref = {"analyze": {"speedup": 10.0}, "meta": {"git_sha": "other"}}
-    assert bench_run.gate(artifact, ref) == 0
-
-
-def test_run_metadata_stamps_without_failing():
-    meta = _bench_run().run_metadata()
-    assert meta["git_sha"] and len(meta["git_sha"]) == 40
-    assert meta["timestamp_utc"].startswith("20")
-    assert meta["jax"] and meta["device_count"] >= 1

@@ -11,6 +11,7 @@ from repro.core import sweep as S
 from repro.core import topology as T
 from repro.core.analysis import AnalysisEngine, apsp_dense
 from repro.core.analysis.paths import shortest_path_multiplicity
+from repro.core.analysis.wavefront import batched_count, batched_dist_mult
 from repro.core.routing import assign
 
 
@@ -65,7 +66,7 @@ def test_batched_apsp_matches_dense_apsp():
 def test_batched_dist_mult_matches_engine():
     graphs = _small_mixed_graphs()
     _, adj = S._stack_seeds(graphs)
-    dist, mult = S.batched_dist_mult(adj, S._batched_count(False))
+    dist, mult = batched_dist_mult(adj, use_kernel=False)
     for i, g in enumerate(graphs):
         want_d, want_m = shortest_path_multiplicity(g, use_kernel=False)
         np.testing.assert_array_equal(dist[i, :g.n, :g.n], want_d)
@@ -93,10 +94,10 @@ def test_ecmp_all_pairs_batched_product():
     with running the accumulation per graph."""
     graphs = _small_mixed_graphs()
     _, adj = S._stack_seeds(graphs)
-    dist, mult = S.batched_dist_mult(adj, S._batched_count(False))
+    dist, mult = batched_dist_mult(adj, use_kernel=False)
     loads = assign.ecmp_all_pairs_loads(dist, mult,
                                         adj.astype(np.float64),
-                                        product=S._batched_count(False))
+                                        product=batched_count(False))
     for i, g in enumerate(graphs):
         want = assign.ecmp_all_pairs_loads(
             dist[i, :g.n, :g.n], mult[i, :g.n, :g.n],

@@ -8,7 +8,8 @@ import pytest
 
 from repro.core import topology as topo
 from repro.core import traffic, workload
-from repro.core.analysis.wavefront import wavefront_dist_mult
+from repro.core.analysis.wavefront import (batched_dist_mult,
+                                          wavefront_dist_mult)
 from repro.core.routing import assign
 from repro.core.routing.throughput import max_concurrent_flow
 from repro.core.traffic import (TrafficSpec, check_grid,
@@ -250,13 +251,15 @@ def test_demand_matrix_shim_equivalence():
 
 # -------------------------------------------------- demand-weighted engine
 
-def test_ecmp_demand_loads_matches_all_pairs_on_ones():
+@pytest.mark.parametrize("use_kernel", [False, True])
+def test_ecmp_demand_loads_matches_all_pairs_on_ones(use_kernel):
     g = topo.make("jellyfish", n=36, r=6, seed=2)
     adj, dist, mult = _dist_mult(g)
     ones = np.ones((g.n, g.n))
     np.fill_diagonal(ones, 0.0)
-    ref = assign.ecmp_all_pairs_loads(dist, mult, adj, use_kernel=False)
-    got = assign.ecmp_demand_loads(dist, mult, adj, ones, use_kernel=False)
+    ref = assign.ecmp_all_pairs_loads(dist, mult, adj, use_kernel=use_kernel)
+    got = assign.ecmp_demand_loads(dist, mult, adj, ones,
+                                   use_kernel=use_kernel)
     np.testing.assert_allclose(got, ref, rtol=1e-12)
 
 
@@ -452,7 +455,7 @@ def test_link_cell_metrics_match_full_matrix_formula(case, path):
     from repro.core.traffic import scenarios
 
     g, adj, demand = _metrics_case(case)
-    dist, mult = scenarios._dist_mult(adj, True)
+    dist, mult = batched_dist_mult(adj)
     cells = np.flatnonzero(g.adjacency_dense())
     if path == "host":
         loads = assign.ecmp_demand_loads(dist, mult, adj, demand,
