@@ -410,7 +410,7 @@ def batched_dist_mult(adj: np.ndarray, use_kernel: bool = True
 
 @functools.lru_cache(maxsize=None)
 def _ecmp_fn(batched: bool, block: int, interpret: bool,
-             weighted: bool = False):
+             weighted: bool = False, shared_graph: bool = False):
     from ... import kernels
     from ...kernels.semiring import (COUNTING, semiring_matmul_batched_pallas,
                                      semiring_matmul_pallas)
@@ -447,6 +447,16 @@ def _ecmp_fn(batched: bool, block: int, interpret: bool,
 
     if weighted:
         def run_weighted(dist, mult, adj, demand):
+            # demand goes up at its own size: its phantom cells are 0
+            pad = dist.shape[-1] - demand.shape[-1]
+            demand = jnp.pad(demand, [(0, 0)] * (demand.ndim - 2)
+                             + [(0, pad)] * 2)
+            if shared_graph:
+                # one (p, p) graph under the (c, p, p) demand, broadcast
+                # before the loop: each level's two products stay the
+                # batched calls on the values a stacked graph gives
+                dist, mult, adj = (jnp.broadcast_to(x, demand.shape)
+                                   for x in (dist, mult, adj))
             return accumulate(dist, mult, adj, demand)
 
         return jax.jit(run_weighted)
@@ -470,17 +480,22 @@ def ecmp_loads_device(dist: jnp.ndarray, mult: jnp.ndarray, adj: jnp.ndarray,
     case); a (.., p, p) ``demand`` operand seeds the recurrence with that
     pair's volume instead, which is the whole batched-traffic engine
     (`routing.assign.ecmp_demand_loads`): diagonal and unreachable demand
-    never enters the level sets, so it is dropped, not routed. Operands
-    must share a (.., p, p) block-multiple shape (phantom padding: dist
-    +inf rows, mult/adj/demand 0). Returns the device (.., p, p) loads.
+    never enters the level sets, so it is dropped, not routed. dist, mult
+    and adj share a (.., p, p) block-multiple shape (phantom padding: dist
+    +inf rows, mult/adj 0); a (c, .., ..) ``demand`` stack may sit on one
+    shared (p, p) graph, which the program broadcasts, and may come
+    unpadded, (.., n, n), which the program pads with 0. Returns the
+    device (.., p, p) loads.
     """
     interpret = resolve_interpret(interpret)
     p = dist.shape[-1]
-    block = _fit_block(p, block, batched=dist.ndim == 3)
+    batched = (dist if demand is None else demand).ndim == 3
+    block = _fit_block(p, block, batched=batched)
     if demand is None:
-        return _ecmp_fn(dist.ndim == 3, block, interpret)(dist, mult, adj)
-    return _ecmp_fn(dist.ndim == 3, block, interpret,
-                    weighted=True)(dist, mult, adj, demand)
+        return _ecmp_fn(batched, block, interpret)(dist, mult, adj)
+    return _ecmp_fn(batched, block, interpret, weighted=True,
+                    shared_graph=batched and dist.ndim == 2)(
+        dist, mult, adj, demand)
 
 
 @functools.lru_cache(maxsize=None)

@@ -45,7 +45,7 @@ from ... import obs
 from ..graph import Graph
 
 __all__ = ["demand_matrix", "ecmp_link_loads", "ecmp_all_pairs_loads",
-           "ecmp_demand_loads", "walk_slack_link_loads",
+           "ecmp_demand_loads", "upload_graph", "walk_slack_link_loads",
            "directed_to_link_loads", "link_load_stats", "count_product",
            "padded_neighbors", "sample_columns", "mask_unreachable_demand"]
 
@@ -271,9 +271,8 @@ def ecmp_all_pairs_loads(dist: np.ndarray, mult: np.ndarray, adj: np.ndarray,
     return _brandes_host(dist, mult, adj, 1.0, product)
 
 
-def ecmp_demand_loads(dist: np.ndarray, mult: np.ndarray, adj: np.ndarray,
-                      demand: np.ndarray, use_kernel: bool = True, *,
-                      device: bool = False):
+def ecmp_demand_loads(dist, mult, adj, demand: np.ndarray,
+                      use_kernel: bool = True, *, device: bool = False):
     """Directed ECMP link loads of *arbitrary* (stacked) demand, O(diameter).
 
     The demand-weighted generalization of :func:`ecmp_all_pairs_loads`:
@@ -287,22 +286,23 @@ def ecmp_demand_loads(dist: np.ndarray, mult: np.ndarray, adj: np.ndarray,
 
     Demand on the diagonal and on unreachable pairs is dropped, never
     routed (contract: `core.traffic.spec`); the level masks are gated on
-    finite distance, so partitioned graphs are first-class. All four
-    operands accept a leading batch axis and broadcast against each other
-    — one graph against an (S, n, n) demand stack, or per-sample graphs
-    (the traffic x failure grid) against per-sample demand. The kernel
-    default runs the whole accumulation device-resident
+    finite distance, so partitioned graphs are first-class. The demand may
+    be one (n, n) matrix or a (C, n, n) stack. The graph (dist, mult, adj)
+    is either one shared (n, n) graph, which stays 2-D — on the kernel path
+    it goes up once, or not at all when the caller passes the padded device
+    operands of :func:`upload_graph`, and each call uploads only the demand
+    — or per-sample (C, n, n) graphs (the traffic x failure grid), which
+    are broadcast against the demand on the host and uploaded with it. The
+    kernel default runs the whole accumulation device-resident
     (`analysis.wavefront.ecmp_loads_device` with its weighted variant);
     ``use_kernel=False`` is the f64 host oracle. Returns the directed
     ``(.., n, n)`` load matrix: host float64, or with ``device=True`` on
     the kernel path the device float32 loads, sliced on the device and
     never downloaded (the host oracle ignores ``device``).
     """
-    dist = np.asarray(dist)
-    mult = np.asarray(mult)
-    adj = np.asarray(adj)
     demand = np.asarray(demand, np.float64)
-    if max(dist.ndim, demand.ndim) == 3:
+    if 3 in (np.ndim(dist), np.ndim(mult), np.ndim(adj)):
+        dist, mult, adj = (np.asarray(x) for x in (dist, mult, adj))
         shape = np.broadcast_shapes(dist.shape, mult.shape, adj.shape,
                                     demand.shape)
         with obs.span("traffic.host"):
@@ -312,7 +312,42 @@ def ecmp_demand_loads(dist: np.ndarray, mult: np.ndarray, adj: np.ndarray,
             demand = np.ascontiguousarray(np.broadcast_to(demand, shape))
     if use_kernel:
         return _ecmp_device("traffic", dist, mult, adj, demand, keep=device)
+    dist, mult, adj = (np.asarray(x) for x in (dist, mult, adj))
     return _brandes_host(dist, mult, adj, demand, count_product(False))
+
+
+def upload_graph(dist: np.ndarray, mult: np.ndarray, adj: np.ndarray):
+    """One (n, n) graph as the device operands every stacked pass of
+    :func:`ecmp_demand_loads` can share: dist (+inf), mult and adjacency
+    (0) padded to the (p, p) of a batched problem, float32, uploaded once
+    each (``h2d_bytes.traffic_dist`` / ``_mult`` / ``_adjacency``)."""
+    from ..analysis.wavefront import pad_block
+
+    p, _ = pad_block(np.shape(dist)[-1], batched=True)
+    return _graph_operands("traffic", p, dist, mult, adj)
+
+
+def _graph_operands(stage: str, p: int, dist, mult, adj):
+    """dist, mult and adj padded to (.., p, p) float32 and uploaded in that
+    order; an operand already on the device was padded by
+    :func:`upload_graph` and is taken as it is. Traced, the innermost live
+    span counts each upload of the graph in ``graph_uploads``."""
+    import jax
+
+    from ... import transfers
+    from ..analysis.wavefront import pad_operand
+
+    def operand(x, fill, what):
+        if isinstance(x, jax.Array):
+            return x
+        with obs.span(f"{stage}.host"):
+            x = pad_operand(x, p, fill)
+        return transfers.upload(x, stage, f"{stage}_{what}")
+
+    if not all(isinstance(x, jax.Array) for x in (dist, mult, adj)):
+        obs.current().inc("graph_uploads", 1)
+    return (operand(dist, np.inf, "dist"), operand(mult, 0.0, "mult"),
+            operand(adj, 0.0, "adjacency"))
 
 
 def _brandes_host(dist: np.ndarray, mult: np.ndarray, adj: np.ndarray,
@@ -338,15 +373,19 @@ def _brandes_host(dist: np.ndarray, mult: np.ndarray, adj: np.ndarray,
     return adj * acc
 
 
-def _ecmp_device(stage: str, dist: np.ndarray, mult: np.ndarray,
-                 adj: np.ndarray, demand: Optional[np.ndarray] = None,
+def _ecmp_device(stage: str, dist, mult, adj,
+                 demand: Optional[np.ndarray] = None,
                  mesh=None, keep: bool = False):
     """Pad -> device Brandes accumulation -> sliced loads: the one upload
     seam of both ECMP entry points.
 
-    Each operand is padded and uploaded in turn, so one padded host copy
-    lives at a time: spans ``<stage>.host`` / ``.h2d`` / ``.wait`` /
-    ``.d2h`` and byte counters ``h2d_bytes.<stage>_<operand>``,
+    dist, mult and adj are host (n, n) or (C, n, n) arrays, padded and
+    uploaded in turn so that one padded host copy lives at a time, or the
+    padded (p, p) device operands of :func:`upload_graph`, used as they
+    are. The (n, n) or (C, n, n) demand goes up as float32 at its own size
+    and the program pads it; a 2-D graph under a stacked demand is
+    broadcast inside the program. Spans ``<stage>.host`` / ``.h2d`` /
+    ``.wait`` / ``.d2h`` and byte counters ``h2d_bytes.<stage>_<operand>``,
     ``d2h_bytes.<stage>_loads`` (`repro.transfers`). ``demand=None`` is
     uniform all-pairs demand. With a multi-device ``mesh`` the uniform
     accumulation runs shard-local over source rows
@@ -355,10 +394,10 @@ def _ecmp_device(stage: str, dist: np.ndarray, mult: np.ndarray,
     float32 loads on the device, never downloaded.
     """
     from ... import transfers
-    from ..analysis.wavefront import ecmp_loads_device, pad_block, pad_operand
+    from ..analysis.wavefront import ecmp_loads_device, pad_block
 
-    n = np.shape(dist)[-1]
-    batched = np.ndim(dist) == 3
+    n = np.shape(dist if demand is None else demand)[-1]
+    batched = 3 in (np.ndim(dist), np.ndim(demand))
     sharded = mesh is not None and mesh.size > 1
     if sharded:
         from ..analysis.distributed import (ROW_AXIS, ecmp_loads_sharded,
@@ -369,18 +408,14 @@ def _ecmp_device(stage: str, dist: np.ndarray, mult: np.ndarray,
     else:
         p, block = pad_block(n, batched=batched)
 
-    def operand(x, fill, what):
-        with obs.span(f"{stage}.host"):
-            x = pad_operand(x, p, fill)
-        return transfers.upload(x, stage, f"{stage}_{what}")
-
-    operands = (operand(dist, np.inf, "dist"), operand(mult, 0.0, "mult"),
-                operand(adj, 0.0, "adjacency"))
+    operands = _graph_operands(stage, p, dist, mult, adj)
     if sharded:
         loads = ecmp_loads_sharded(*operands, mesh, block=block)
     else:
         if demand is not None:
-            demand = operand(demand, 0.0, "demand")
+            with obs.span(f"{stage}.host"):
+                demand = np.asarray(demand, np.float32)
+            demand = transfers.upload(demand, stage, f"{stage}_demand")
         loads = ecmp_loads_device(*operands, demand=demand, block=block)
     sl = (Ellipsis, slice(None, n), slice(None, n))
     if keep:

@@ -4,7 +4,9 @@
 (a :class:`~repro.core.traffic.spec.TrafficSpec`, a flag-grammar string,
 or raw matrices) through ONE demand-weighted Brandes accumulation
 (`routing.assign.ecmp_demand_loads`, the stacked device engine behind
-`resilience.degradation`) and reduces per-matrix congestion metrics with
+`resilience.degradation`) on the one (n, n) graph, which goes up to the
+device once per call as 2-D padded operands while each stacked pass
+uploads only its demand, and reduces per-matrix congestion metrics with
 vectorized masked reductions over the fabric's directed-link cells — no
 per-matrix Python loop anywhere on the device path (``mask_chunk`` only
 splits oversized batches to bound device memory, reusing the resilience
@@ -186,14 +188,16 @@ def evaluate_traffic_batch(g: Graph, demand: DemandLike,
 
     Traced, demand generation is the ``demand.host`` span and the passes
     run inside ``traffic.scenario``, whose ``products`` attribute counts
-    the weighted counting products (2 per BFS level per matrix) and
-    ``link_cells`` the directed-link cells each matrix is reduced over.
-    On the kernel path each pass's loads stay on the device and only
-    their link cells come down.
+    the weighted counting products (2 per BFS level per matrix),
+    ``link_cells`` the directed-link cells each matrix is reduced over and
+    ``graph_uploads`` the times dist, mult and adjacency went up. On the
+    kernel path the (n, n) graph goes up once per call, padded 2-D, each
+    pass uploads only its (chunk, n, n) demand, and each pass's loads stay
+    on the device: only their link cells come down.
     """
     from ... import transfers
     from ..resilience.degradation import _auto_chunk
-    from ..routing.assign import ecmp_demand_loads
+    from ..routing.assign import ecmp_demand_loads, upload_graph
 
     batch, label = demand_batch(g, demand)
     s, n = len(batch), g.n
@@ -211,13 +215,15 @@ def evaluate_traffic_batch(g: Graph, demand: DemandLike,
                   link_cells=len(cells)) as sp:
         with obs.span("traffic.host"):
             weights = _demand_weights(dist)[1:]
-        device_cells = transfers.upload(
-            cells.astype(np.int32), "traffic",
-            "traffic_link_cells") if use_kernel else None
+        device_cells, graph = None, (dist, mult, adj)
+        if use_kernel:
+            device_cells = transfers.upload(
+                cells.astype(np.int32), "traffic", "traffic_link_cells")
+            graph = upload_graph(dist, mult, adj)
         for lo in range(0, s, mask_chunk):
             d = batch[lo:lo + mask_chunk]
-            loads = ecmp_demand_loads(dist, mult, adj, d,
-                                      use_kernel=use_kernel, device=True)
+            loads = ecmp_demand_loads(*graph, d, use_kernel=use_kernel,
+                                      device=True)
             link = _link_loads(loads, cells, device_cells)
             with obs.span("traffic.host"):
                 parts.append(_traffic_metrics(link, d, weights,
@@ -315,7 +321,7 @@ def saturation_search(g: Graph, spec: Union[str, TrafficSpec],
     with a bootstrap 95% CI. Demand that routes nothing anywhere raises.
     """
     from ..analysis.estimator import bootstrap_ci
-    from ..routing.assign import ecmp_demand_loads
+    from ..routing.assign import ecmp_demand_loads, upload_graph
     from ..resilience.degradation import _auto_chunk
 
     spec = as_spec(spec)
@@ -329,14 +335,16 @@ def saturation_search(g: Graph, spec: Union[str, TrafficSpec],
     def peaks_for(stack: np.ndarray) -> np.ndarray:
         out = np.empty(len(stack))
         for lo in range(0, len(stack), mask_chunk):
-            loads = ecmp_demand_loads(dist, mult, adj,
-                                      stack[lo:lo + mask_chunk],
+            loads = ecmp_demand_loads(*graph, stack[lo:lo + mask_chunk],
                                       use_kernel=use_kernel)
             out[lo:lo + mask_chunk] = loads.reshape(len(loads), -1).max(1)
         return out
 
     with obs.span("traffic.saturation", cat="traffic", demand=label,
                   samples=s, routers=n, rounds=rounds, grid=grid) as sp:
+        # the graph goes up once; each pass uploads only its demand
+        graph = (upload_graph(dist, mult, adj) if use_kernel
+                 else (dist, mult, adj))
         probe = float(spec.rate) if spec.rate > 0 else 1.0
         peak0 = peaks_for(base * (probe / spec.rate if spec.rate > 0
                                   else 1.0))

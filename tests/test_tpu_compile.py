@@ -75,12 +75,19 @@ def test_batched_wavefront(one_chip):
              ((4, p, p), jnp.float32), sharding=one_chip)
 
 
-@pytest.mark.parametrize("batched", [False, True], ids=["single", "batched"])
-def test_weighted_ecmp_loop(one_chip, batched):
+@pytest.mark.parametrize("case", ["single", "batched", "shared"])
+def test_weighted_ecmp_loop(one_chip, case):
+    # shared: the traffic engine's pass, one (p, p) graph under a 2-matrix
+    # demand uploaded at its own size
+    batched = case != "single"
     p, block = WF.pad_block(3362, batched=batched)
-    shape = (8, p, p) if batched else (p, p)
-    _compile(WF._ecmp_fn(batched, block, False, weighted=True),
-             *[(shape, jnp.float32)] * 4, sharding=one_chip)
+    graph = (8, p, p) if case == "batched" else (p, p)
+    demand = {"single": (p, p), "batched": (8, p, p),
+              "shared": (2, 3362, 3362)}[case]
+    _compile(WF._ecmp_fn(batched, block, False, weighted=True,
+                         shared_graph=case == "shared"),
+             *[(graph, jnp.float32)] * 3, (demand, jnp.float32),
+             sharding=one_chip)
 
 
 def test_slack_counts_program_slimfly(one_chip):

@@ -518,6 +518,67 @@ def test_resilience_demand_uniform_matches_legacy_tput():
     assert "dropped_demand_frac" in dem
 
 
+@pytest.mark.parametrize("check", ["loads", "chunks", "saturation"])
+@pytest.mark.parametrize("graph", ["jellyfish", "slimfly"])
+def test_shared_graph_matches_stacked_graph(graph, check, monkeypatch):
+    """One 2-D graph under a stacked demand, uploaded once and broadcast
+    inside the program, gives the loads of the same graph stacked on the
+    host bit for bit, and so the same metrics however the batch is split."""
+    g = (topo.make("jellyfish", n=36, r=6, seed=2) if graph == "jellyfish"
+         else topo.make("slimfly", q=5))
+    adj, dist, mult = _dist_mult(g)
+    spec = "hotspot:zipf_a=1.4,samples=3,seed=7"
+    if check == "loads":
+        batch = TrafficSpec.parse(spec).batch(g)
+        stacked = assign.ecmp_demand_loads(
+            *(np.broadcast_to(x, batch.shape) for x in (dist, mult, adj)),
+            batch)
+        shared = assign.ecmp_demand_loads(
+            *assign.upload_graph(dist, mult, adj), batch, device=True)
+        np.testing.assert_array_equal(np.asarray(shared, np.float64),
+                                      stacked)
+        np.testing.assert_array_equal(
+            assign.ecmp_demand_loads(dist, mult, adj, batch), stacked)
+    elif check == "chunks":
+        original, loads = assign.ecmp_demand_loads, []
+
+        def spy(*args, **kw):
+            out = original(*args, **kw)
+            loads[-1].append(np.asarray(out))
+            return out
+
+        monkeypatch.setattr(assign, "ecmp_demand_loads", spy)
+        runs = []
+        for c in (1, 2, 3):
+            loads.append([])
+            runs.append(evaluate_traffic_batch(g, spec, mask_chunk=c))
+        for run, passes in zip(runs[1:], loads[1:]):
+            np.testing.assert_array_equal(np.concatenate(passes),
+                                          np.concatenate(loads[0]))
+            for k in traffic.TRAFFIC_METRICS:
+                if k in ("avg_hops", "dropped_demand_frac"):
+                    # the host's float64 mat-vec of the demand rounds by
+                    # the number of rows it is given
+                    np.testing.assert_allclose(run[k], runs[0][k],
+                                               rtol=1e-15)
+                else:
+                    np.testing.assert_array_equal(run[k], runs[0][k])
+    else:
+        spec = "hotspot:zipf_a=1.4,samples=2,seed=7"
+        got = saturation_search(g, spec, rounds=2, grid=3)
+        original = assign.ecmp_demand_loads
+
+        def stacked(dist, mult, adj, demand, **kw):
+            # the graph stacked on the host against every pass's demand
+            n = demand.shape[-1]
+            return original(*(np.broadcast_to(np.asarray(x)[:n, :n],
+                                              demand.shape)
+                              for x in (dist, mult, adj)), demand, **kw)
+
+        monkeypatch.setattr(assign, "ecmp_demand_loads", stacked)
+        assert saturation_search(g, spec, rounds=2, grid=3) == got
+
+
 # ------------------------------------------------------- saturation search
 
 def test_saturation_search_ring_tornado_closed_form():
